@@ -9,6 +9,7 @@ import sys
 import time
 
 from benchmarks.common import emit
+from repro.compile_cache import enable_compile_cache
 
 SECTIONS = [
     "fig9_throughput",
@@ -41,6 +42,7 @@ def main(argv: list = None) -> None:
         print(f"valid sections: {', '.join(SECTIONS)}", file=sys.stderr)
         raise SystemExit(2)
     want = want or SECTIONS
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for section in SECTIONS:
         if section not in want:

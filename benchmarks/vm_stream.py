@@ -8,9 +8,10 @@ axis of ONE launch (no per-slice `jax.vmap`). This benchmark measures the
 two claims that rebuild makes:
 
   * **streaming**: steady-state dispatch over operands spanning >= 4 word
-    grid blocks, reported as effective GB/s against the shared HBM
-    roofline constant (`repro.hw.HBM_BW` — the same denominator the
-    dry-run roofline analysis prices against).
+    grid blocks, reported as effective GB/s against the running chip's
+    published HBM bandwidth (`repro.hw.peaks(device_kind)` — the table the
+    dry-run roofline analysis prices against; a chip missing from it
+    fails the run).
   * **fused reduction**: count-only analytics (`reduce="popcount"`) keep
     the output planes in VMEM scratch — only ``(n_out, batch)`` int32
     counts reach HBM — so the fused path's traffic is the plane read
@@ -23,8 +24,9 @@ must equal the float32-weighted count sum. The operand must genuinely
 span >= 4 grid blocks or the run aborts — a single-block "stream" would
 measure nothing.
 
-Wall-clock rows carry an `interpret` flag: off-TPU the kernel runs in
-Pallas interpret mode, where GB/s reflects the interpreter, not HBM —
+Wall-clock rows carry an `interpret` flag: on the CPU the kernel runs in
+Pallas interpret mode, where GB/s reflects the interpreter, not HBM, so
+the HBM fractions are null there (not measured) —
 `benchmarks/perf_gate.py` only compares bandwidth metrics between runs of
 equal operand size with the flag unset on both sides.
 
@@ -32,14 +34,15 @@ Writes BENCH_vm_stream.json at the repo root.
 """
 from __future__ import annotations
 
-import numpy as np
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from benchmarks.common import Row, emit, measure_wall, smoke_mode, \
     write_bench_json
 from repro.core import arith_compiler, compiler, lowering
 from repro.core.commands import Program
-from repro.hw import HBM_BW
+from repro import hw
 from repro.kernels import vm as vmk
 from repro.kernels.common import LANE, pick_block, round_up, use_interpret
 from repro.ops.popcount import popcount_words
@@ -78,6 +81,12 @@ def run() -> list[Row]:
     batch = SMOKE_BATCH if smoke else FULL_BATCH
     iters = 3 if smoke else 5
     interp = use_interpret()
+    # bandwidth fractions only mean something against the chip that ran
+    hbm_bw = None if interp else hw.peaks(jax.devices()[0].device_kind).hbm_bw
+
+    def hbm_frac(gbps):
+        return None if hbm_bw is None else round(gbps * 1e9 / hbm_bw, 4)
+
     rng = np.random.default_rng(0)
     rows: list[Row] = []
     jrows: list[dict] = []
@@ -137,7 +146,7 @@ def run() -> list[Row]:
         rows.append((
             f"vm_stream/{name}", w_fused["wall_steady_us"],
             f"blocks={n_blocks} fused_gbps={fused_gbps:.2f} "
-            f"hbm_frac={fused_gbps * 1e9 / HBM_BW:.3f} "
+            f"hbm_frac={hbm_frac(fused_gbps)} "
             f"mat_gbps={mat_gbps:.2f} "
             f"saved_kb={writeback / 1024:.0f} "
             f"interpret={'yes' if interp else 'no'} bit_identity=yes"))
@@ -154,11 +163,11 @@ def run() -> list[Row]:
             "mat_first_us": round(w_mat["wall_first_us"], 1),
             "mat_steady_us": round(w_mat["wall_steady_us"], 1),
             "mat_gbps": round(mat_gbps, 3),
-            "mat_hbm_frac": round(mat_gbps * 1e9 / HBM_BW, 4),
+            "mat_hbm_frac": hbm_frac(mat_gbps),
             "fused_first_us": round(w_fused["wall_first_us"], 1),
             "fused_steady_us": round(w_fused["wall_steady_us"], 1),
             "fused_gbps": round(fused_gbps, 3),
-            "fused_hbm_frac": round(fused_gbps * 1e9 / HBM_BW, 4),
+            "fused_hbm_frac": hbm_frac(fused_gbps),
             "writeback_saved_bytes": writeback,
         })
 

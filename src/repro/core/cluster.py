@@ -56,21 +56,11 @@ DEFAULT_PLACEMENT_CHIPS = 8
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """`shard_map` across jax versions (experimental vs top-level API).
-
-    Replication checking is disabled: bodies mix per-shard outputs with
-    tree-psum'd (replicated) scalars, which the static rep checker of
-    older jax cannot type through `ppermute`.
-    """
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-    try:
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    except TypeError:   # jax >= 0.6 renamed check_rep -> check_vma
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
+    """`jax.shard_map` with replication checking off: bodies mix per-shard
+    outputs with tree-psum'd (replicated) scalars, which the varying-axes
+    checker cannot type through `ppermute`."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def tree_psum(x: jax.Array, axis_name: str, n: int) -> jax.Array:

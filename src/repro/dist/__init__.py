@@ -3,7 +3,6 @@ training loops, and elastic rescale planning.
 
 This package was referenced throughout the seed (models, kernels, launch,
 train) but absent from it; it is reconstructed here against the behavior the
-tests and call sites pin down. Everything degrades gracefully on older JAX
-(no `shard_map`/`pvary`): sharding constraints become identity outside an
-`axis_rules` context and `match_vma` is a no-op when vma typing is absent.
+tests and call sites pin down. Sharding constraints are the identity
+outside an `axis_rules` context.
 """

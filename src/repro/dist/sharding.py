@@ -211,16 +211,11 @@ def tree_shardings(shapes: Any, specs: Any, mesh,
 def match_vma(x: Any, ref: jax.Array) -> Any:
     """Make every leaf of `x` vary over (at least) the manual axes `ref`
     varies over — needed to seed scan/loop carries inside shard_map regions
-    under vma typing. On JAX without `pvary`/`typeof` this is a no-op."""
-    pvary = getattr(jax.lax, "pvary", None)
-    typeof = getattr(jax, "typeof", None)
-    if pvary is None or typeof is None:
-        return x
-    ref_vma = getattr(typeof(ref), "vma", frozenset())
+    under vma typing."""
+    ref_vma = jax.typeof(ref).vma
 
     def one(a):
-        have = getattr(typeof(a), "vma", frozenset())
-        missing = tuple(sorted(ref_vma - have))
-        return pvary(a, missing) if missing else a
+        missing = tuple(sorted(ref_vma - jax.typeof(a).vma))
+        return jax.lax.pcast(a, missing, to="varying") if missing else a
 
     return jax.tree.map(one, x)

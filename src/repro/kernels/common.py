@@ -10,8 +10,21 @@ import jax.numpy as jnp
 
 @functools.lru_cache(None)
 def use_interpret() -> bool:
-    """Pallas TPU kernels run in interpret mode off-TPU (CPU CI/tests)."""
-    return jax.default_backend() != "tpu"
+    """Whether the Pallas kernels run in interpret mode.
+
+    The kernels are TPU kernels (`pltpu` grid specs and compiler params):
+    they compile for a TPU and are interpreted on the CPU backend, where
+    the tests run. Any other backend raises, so no device silently falls
+    back to the interpreter.
+    """
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas kernels target TPUs (and interpret on CPU); "
+        f"backend {backend!r} is not supported")
 
 
 def round_up(x: int, m: int) -> int:

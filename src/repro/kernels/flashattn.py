@@ -28,18 +28,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import use_interpret
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both without
-# mutating the shared pltpu module
-def _no_compiler_params(*_a, **_k):
-    raise ImportError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        "TPUCompilerParams; this jax version is unsupported by flashattn")
-
-
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams",
-                                  _no_compiler_params))
-
 NEG_INF = -1e30
 
 
@@ -140,7 +128,7 @@ def flash_attention_kernel(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((bq,), jnp.float32),      # running denom
             pltpu.VMEM((bq, hd), jnp.float32),   # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=use_interpret(),
@@ -281,7 +269,7 @@ def flash_attention_fwd_kernel(q, k, v, causal=True, block_q=512,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=use_interpret(),
@@ -334,7 +322,7 @@ def flash_attention_bwd_kernel(q, k, v, o, lse, do, causal=True,
                                lambda b, h, qi, ki: (b, h, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=use_interpret(),
@@ -364,7 +352,7 @@ def flash_attention_bwd_kernel(q, k, v, o, lse, do, causal=True,
         ],
         scratch_shapes=[pltpu.VMEM((bk, hd), jnp.float32),
                         pltpu.VMEM((bk, hd), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=use_interpret(),

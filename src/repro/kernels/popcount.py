@@ -1,9 +1,11 @@
 """Popcount reduction kernel: SWAR per word, per-block partial sums.
 
 Buddy keeps `bitcount` on the CPU (paper §8.1); on TPU we keep it resident:
-each grid cell reduces an (8, bw) uint32 block to one int32 partial with the
-Hacker's-Delight SWAR sequence on the VPU, and the partials are summed by XLA.
-Bytes moved: N words in, N/(br*bw) partials out — pure memory-bound streaming.
+each grid cell popcounts an (8, bw) uint32 block with the Hacker's-Delight
+SWAR sequence on the VPU and adds its per-row sums into a VMEM-resident
+(8, 1) partial that stays put across the word-block axis; XLA sums the
+partials. Bytes moved: N words in, N/bw partials out — pure memory-bound
+streaming.
 """
 from __future__ import annotations
 
@@ -12,6 +14,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import (LANE, SUBLANE, pad_to, pick_block, round_up,
                                   use_interpret)
@@ -25,7 +28,15 @@ def _popcount_swar(w):
 
 
 def _kern(x_ref, o_ref):
-    o_ref[0, 0] = _popcount_swar(x_ref[...]).astype(jnp.int32).sum()
+    # the (br, 1) output block's index map is constant in j, so it
+    # accumulates across the word-block axis in VMEM (same epilogue shape
+    # as the megakernel's fused count in `kernels.vm`)
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    o_ref[...] += jnp.sum(_popcount_swar(x_ref[...]).astype(jnp.int32),
+                          axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit,
@@ -42,8 +53,10 @@ def popcount_kernel(words: jax.Array, block_rows: int = SUBLANE,
         _kern,
         grid=(rp // br, wp // bw),
         in_specs=[pl.BlockSpec((br, bw), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((rp // br, wp // bw), jnp.int32),
+        out_specs=pl.BlockSpec((None, br, 1), lambda i, j: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((rp // br, br, 1), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=use_interpret(),
     )(x)
     # int32 is exact up to 2^31 set bits (= 256 MiB of all-ones input).

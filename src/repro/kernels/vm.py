@@ -16,7 +16,7 @@ stream through a fixed ``(n_rows, block_cols)`` VMEM footprint. Pallas
 pipelines the grid with double-buffered HBM→VMEM block copies: while the
 sequencer chews block j, block j+1's async copy is in flight — the
 copy/compute overlap that puts the kernel on the HBM bandwidth roofline
-(measured by ``benchmarks/vm_stream.py`` against `repro.hw.HBM_BW`).
+(measured by ``benchmarks/vm_stream.py`` against `repro.hw.PEAKS`).
 
 Fused reduction epilogue (``reduce=``): bitwise programs are word-local, so
 count-only queries (the scheduler's popcount / aggregate result modes)
@@ -60,11 +60,6 @@ _N_FIXED = len(FIXED_ROWS)
 DEFAULT_BLOCK_COLS = 2048
 
 REDUCE_MODES = (None, "popcount", "aggregate")
-
-# jax renamed TPUCompilerParams -> CompilerParams; tolerate both (and very
-# old versions with neither — then no dimension semantics are passed).
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
 
 
 def _popcount_block(w: jax.Array) -> jax.Array:
@@ -187,13 +182,18 @@ def _vm_call(table: jax.Array, plane: jax.Array, errors=None, mask=None, *,
         in_specs.append(
             pl.BlockSpec((None, ep, bw), lambda b, j, tbl: (b, 0, j)))
     if with_mask:
-        # (1, words) shared mask or (B, words) per-batch mask
+        # (1, words) shared mask or (B, words) per-batch mask. The batch
+        # axis is carried as (mb, 1, words) so the block's last two dims
+        # are (1, bw): the 1 equals the array's own dim, which Mosaic
+        # accepts where a (1, bw) block over an (mb, words) array is not
+        # sublane-aligned and is refused.
         mb = mask.shape[0]
-        operands.append(pad_to(jnp.asarray(mask, jnp.uint32), (mb, wp)))
-        if mb == 1:
-            in_specs.append(pl.BlockSpec((1, bw), lambda b, j, tbl: (0, j)))
-        else:
-            in_specs.append(pl.BlockSpec((1, bw), lambda b, j, tbl: (b, j)))
+        operands.append(pad_to(jnp.asarray(mask, jnp.uint32),
+                               (mb, wp)).reshape(mb, 1, wp))
+        in_specs.append(pl.BlockSpec(
+            (None, 1, bw),
+            (lambda b, j, tbl: (0, 0, j)) if mb == 1
+            else (lambda b, j, tbl: (b, 0, j))))
     if reduce is None:
         # exact output rows/words: Pallas masks the partial trailing block,
         # so no padded HBM writeback escapes the dispatch
@@ -213,17 +213,13 @@ def _vm_call(table: jax.Array, plane: jax.Array, errors=None, mask=None, *,
         out_specs=out_spec,
         scratch_shapes=[pltpu.VMEM((rp, bw), jnp.uint32)],
     )
-    kwargs = {}
-    if _CompilerParams is not None:
-        kwargs["compiler_params"] = _CompilerParams(
-            dimension_semantics=dim_sem)
     out = pl.pallas_call(
         _vm_kernel(n_cmds, out_idx, with_err, with_mask, reduce is not None,
                    w, bw),
         grid_spec=grid_spec,
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=dim_sem),
         interpret=use_interpret(),
-        **kwargs,
     )(*operands)
     return out[..., 0] if reduce is not None else out
 

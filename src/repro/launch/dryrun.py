@@ -20,6 +20,7 @@ import time
 import traceback
 
 
+from repro import hw
 from repro.configs.base import SHAPES, cells, get_config
 from repro.launch.cells import build_cell
 from repro.launch.mesh import make_production_mesh
@@ -44,7 +45,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
               f"lower {t_lower:.1f}s compile {t_compile:.1f}s")
         print("  memory_analysis:", mem)
     rl = analyze(compiled, get_config(arch), SHAPES[shape_name], mesh_name,
-                 chips, arch)
+                 chips, arch, hw.V5E)
     out = rl.to_dict()
     out.update({
         "lower_s": t_lower, "compile_s": t_compile,

@@ -2,9 +2,12 @@
 
 Three terms per (arch x shape x mesh) cell, all in seconds:
 
-    compute    = HLO_FLOPs / (chips x 197e12 bf16 FLOP/s)
-    memory     = HLO_bytes / (chips x 819e9 B/s HBM)
-    collective = collective_bytes / (chips x 50e9 B/s ICI link)
+    compute    = HLO_FLOPs / (chips x peak bf16 FLOP/s)
+    memory     = HLO_bytes / (chips x peak HBM B/s)
+    collective = collective_bytes / (chips x ICI B/s per link)
+
+with the peaks of the target chip looked up in `repro.hw.PEAKS` by its
+``device_kind`` (the dry run's production meshes describe TPU v5e).
 
 HLO_FLOPs / HLO_bytes come from compiled.cost_analysis(). collective_bytes is
 parsed from the post-SPMD optimized HLO text: for every all-gather /
@@ -22,11 +25,8 @@ import dataclasses
 import re
 from typing import Any, Dict, Optional
 
+from repro import hw
 from repro.configs.base import ModelConfig, ShapeConfig
-# TPU v5e per-chip constants live in `repro.hw` (shared with the measured
-# bandwidth benchmark, benchmarks/vm_stream.py); re-exported here for
-# existing importers of roofline.PEAK_FLOPS et al.
-from repro.hw import HBM_BW, ICI_BW, PEAK_FLOPS  # noqa: F401
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1,
@@ -131,27 +131,28 @@ class Roofline:
     collective_bytes: float
     collective_by_kind: Dict[str, Dict[str, float]]
     model_flops_: float
+    peaks: hw.ChipPeaks
     bytes_per_device: Optional[float] = None
     dot_bytes: float = 0.0
 
     @property
     def t_compute(self) -> float:
-        return self.hlo_flops / (self.chips * PEAK_FLOPS)
+        return self.hlo_flops / (self.chips * self.peaks.flops_bf16)
 
     @property
     def t_memory(self) -> float:
-        return self.hlo_bytes / (self.chips * HBM_BW)
+        return self.hlo_bytes / (self.chips * self.peaks.hbm_bw)
 
     @property
     def t_memory_floor(self) -> float:
         """Matmul-attributed traffic only — fusion-granularity independent
         (XLA:CPU fuses less than TPU; the true TPU memory term lies between
         t_memory_floor and t_memory)."""
-        return self.dot_bytes / (self.chips * HBM_BW)
+        return self.dot_bytes / (self.chips * self.peaks.hbm_bw)
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes / (self.chips * ICI_BW)
+        return self.collective_bytes / (self.chips * self.peaks.ici_bw)
 
     @property
     def dominant(self) -> str:
@@ -168,7 +169,7 @@ class Roofline:
         """Fraction of the dominant-term bound that is useful model compute:
         (MODEL_FLOPS / chips / peak) / max(term). 1.0 = the step takes
         exactly as long as the useful flops at peak — the roofline."""
-        t_use = self.model_flops_ / (self.chips * PEAK_FLOPS)
+        t_use = self.model_flops_ / (self.chips * self.peaks.flops_bf16)
         t_step = max(self.t_compute, self.t_memory, self.t_collective)
         return t_use / t_step if t_step else 0.0
 
@@ -192,7 +193,7 @@ class Roofline:
 
 
 def analyze(compiled, cfg: ModelConfig, shape: ShapeConfig, mesh_name: str,
-            chips: int, arch: str) -> Roofline:
+            chips: int, arch: str, device_kind: str) -> Roofline:
     """NOTE: XLA's cost_analysis() counts while (scan) bodies once — useless
     for scan-over-layers models — so FLOPs/bytes/collective-bytes come from
     the trip-count-aware walker in launch/hlocost.py (validated against XLA
@@ -222,4 +223,5 @@ def analyze(compiled, cfg: ModelConfig, shape: ShapeConfig, mesh_name: str,
                     hlo_flops=flops, hlo_bytes=byts,
                     collective_bytes=coll_bytes, collective_by_kind=coll,
                     model_flops_=model_flops(cfg, shape),
+                    peaks=hw.peaks(device_kind),
                     bytes_per_device=mem, dot_bytes=dot_bytes)

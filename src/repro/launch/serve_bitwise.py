@@ -31,6 +31,7 @@ import argparse
 import dataclasses
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.obs import Telemetry
 from repro.service import (ServiceConfig, SloConfig, WorkloadSpec,
                            build_service, poisson_arrivals, query_stream,
@@ -165,6 +166,7 @@ def main(argv=None):
     ap.add_argument("--tick-log", action="store_true",
                     help="stream a dashboard line per serving tick")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     trace_on = args.telemetry or args.trace_out is not None
     tel = Telemetry(trace=trace_on) if trace_on else None

@@ -222,8 +222,8 @@ def reorder_expr(expr: Expr) -> Expr:
 #: below this command count the eager interpreter beats any VM launch
 _INTERP_MAX_CMDS = 2
 #: at/above this command count the Pallas megakernel amortizes its launch —
-#: but only on accelerator devices; off-TPU it runs in interpret mode and
-#: would only slow the host down
+#: but only on a TPU, the one device the kernel compiles for; on the CPU it
+#: runs in interpret mode and would only slow the host down
 _PALLAS_MIN_CMDS = 48
 #: fused-reduction dispatches amortize sooner: the count epilogue runs in
 #: VMEM scratch and skips the output-plane HBM writeback entirely, so the
@@ -244,7 +244,7 @@ def choose_backend(program: Program, device: str,
     if n_cmds <= _INTERP_MAX_CMDS:
         return "interp"
     floor = _PALLAS_MIN_CMDS_FUSED if fused_reduce else _PALLAS_MIN_CMDS
-    if device in ("tpu", "gpu") and n_cmds >= floor:
+    if device == "tpu" and n_cmds >= floor:
         return "pallas"
     return "scan"
 

@@ -266,6 +266,13 @@ class Scheduler:
         # RowClone); arithmetic plans move one row per operand/result plane
         return self.timing.aap_ns * (plan.n_inputs + len(plan.outputs))
 
+    def _count_dispatch(self, backend: str) -> None:
+        """Count one plan-group dispatch under the executor that ran it
+        (``plan_group_dispatches_total{backend=...}``)."""
+        if self.telemetry.metering:
+            self.telemetry.metrics.counter(
+                "plan_group_dispatches_total", backend=backend).inc()
+
     def _operand_words(self, name: str,
                        cse_planes: Optional[Dict[str, jax.Array]]):
         """A bound operand's packed words: catalog row or shared plane."""
@@ -323,6 +330,7 @@ class Scheduler:
             opt = getattr(self.planner.cache, "optimizer", None)
             if opt is not None:
                 backend = opt.backend(plan.program, fused_reduce=True)
+            self._count_dispatch(backend)
             counts = lowering.execute_lowered(
                 plan.lowered, data, outputs=list(plan.outputs),
                 backend=backend, reduce="popcount",
@@ -334,6 +342,7 @@ class Scheduler:
             return None, scalars, 1
         if (rel is not None and rel.mode != "none"
                 and plan.lowered is not None):
+            backend = self.backend     # mitigation runs on the default VM
             out, replicas = self._run_reliable(plan, data)
         elif backend == "interp":
             # degenerate 1-2 command programs: eager micro-op interpreter,
@@ -345,9 +354,11 @@ class Scheduler:
                 plan.lowered, data, outputs=list(plan.outputs),
                 backend=backend)
         else:   # plans built outside the cache fall back to the engine
+            backend = self.backend
             out = engine.execute(plan.program, data,
                                  outputs=list(plan.outputs),
                                  backend=self.backend)
+        self._count_dispatch(backend)
         mask = self.catalog.mask()
         # (n_outputs, len(members), n_words), output planes LSB-first
         masked = jnp.stack([out[o] & mask for o in plan.outputs])
@@ -485,6 +496,7 @@ class Scheduler:
         lp = plan.lowered
         if lp is None:      # plans built outside the cache lower here
             lp = lowering.lower(plan.program)
+        self._count_dispatch(backend)
         if not need_words:
             # scalar-only group: one shard_map launch, only the count
             # matrix crosses the chip boundary

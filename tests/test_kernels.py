@@ -178,3 +178,36 @@ def test_pack_signs_negative_zero():
     got = np.asarray(ops.pack_signs(jnp.asarray(x)))
     exp = np.asarray(ref.pack_signs(jnp.asarray(x)))
     np.testing.assert_array_equal(got, exp)
+
+
+# ---------------------------------------------------------------------------
+# device gating: interpret mode and the peak table
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False),
+                                               ("gpu", None)])
+def test_use_interpret_by_backend(monkeypatch, backend, interpret):
+    """The kernels compile on a TPU, interpret on the CPU, and refuse any
+    other backend rather than silently interpreting there."""
+    import jax
+
+    from repro.kernels.common import use_interpret
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            use_interpret.__wrapped__()
+    else:
+        assert use_interpret.__wrapped__() is interpret
+
+
+def test_peaks_table_keyed_by_device_kind():
+    from repro import hw
+
+    v5e = hw.peaks("TPU v5 lite")
+    assert (v5e.flops_bf16, v5e.hbm_bw, v5e.hbm_bytes) == (197e12, 819e9,
+                                                           16 * 10**9)
+    assert "TPU v5e" in v5e.source
+    with pytest.raises(KeyError, match="no peak entry"):
+        hw.peaks("cpu")

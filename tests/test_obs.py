@@ -311,6 +311,17 @@ def test_metrics_registry_consistent_with_stats(traced_run):
     assert "queries_total" in prom and "tenant_queries_total" in prom
 
 
+def test_plan_group_dispatches_counted_by_backend(traced_run):
+    svc, _, report = traced_run
+    m = svc.telemetry.metrics
+    ran = {b: m.counter("plan_group_dispatches_total", backend=b).value
+           for b in ("interp", "scan", "pallas")}
+    # every plan group and every shared CSE plane is one dispatch
+    assert sum(ran.values()) == report.n_plan_groups + report.n_cse_planes
+    assert ran["scan"] > 0
+    assert ran["pallas"] == 0       # the megakernel is offered on a TPU only
+
+
 def test_stats_registry_matches_legacy_fallback():
     # the same workload served with metering on and fully off must agree
     # on every shared legacy key — the registry keys are true aliases

@@ -17,12 +17,6 @@ from repro.dist.sharding import (DEFAULT_RULES, constrain, resolve_spec,
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# the subprocess tests drive explicit-sharding APIs (jax.sharding.AxisType,
-# jax.shard_map) that this container's JAX does not ship yet
-requires_new_jax = pytest.mark.skipif(
-    not (hasattr(jax.sharding, "AxisType") and hasattr(jax, "shard_map")),
-    reason="needs jax>=0.6 (jax.sharding.AxisType / jax.shard_map)")
-
 
 class FakeMesh:
     """Just enough mesh interface for resolve_spec (axis names + shape)."""
@@ -130,7 +124,6 @@ _SUBPROC_CELL = textwrap.dedent("""
 """)
 
 
-@requires_new_jax
 @pytest.mark.parametrize("arch,kind", [("qwen3_0p6b", "train"),
                                        ("mamba2_1p3b", "decode"),
                                        ("kimi_k2_1t_a32b", "train")])
@@ -143,7 +136,6 @@ def test_cell_lowers_on_host_mesh(arch, kind):
     assert "COMPILED_OK" in r.stdout, r.stderr[-2000:]
 
 
-@requires_new_jax
 def test_majority_allreduce_subprocess():
     code = textwrap.dedent(f"""
         import os

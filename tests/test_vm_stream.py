@@ -118,14 +118,18 @@ def test_materialize_returns_exact_rows_and_words():
 
 
 @pytest.mark.parametrize("batch", BATCHES)
-@pytest.mark.parametrize("with_mask", [False, True])
+@pytest.mark.parametrize("with_mask", [False, True, "per_batch"])
 def test_fused_popcount_equals_materialize_then_popcount(batch, with_mask):
+    """Fused counts equal materialize-then-popcount with no mask, one mask
+    shared by every batch slice (True), and a mask per batch slice (carried
+    into the kernel as a ``(batch, 1, words)`` operand)."""
     prog, ins, outs = _program()
     lp = lowering.lower(prog)
     data = _data(ins, batch)
     plane = lowering.make_plane(lp, data, W, batch=batch)
-    mask = (jnp.asarray(RNG.integers(0, 1 << 32, (W,), dtype=np.uint32))
-            if with_mask else None)
+    mask_shape = {False: None, True: (W,), "per_batch": batch + (W,)}
+    mask = (None if not with_mask else jnp.asarray(
+        RNG.integers(0, 1 << 32, mask_shape[with_mask], dtype=np.uint32)))
     counts = run_megakernel(lp, plane, tuple(outs), block_cols=BLOCK,
                             reduce="popcount", mask=mask)
     rows = run_megakernel(lp, plane, tuple(outs), block_cols=BLOCK)
