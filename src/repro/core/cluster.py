@@ -280,14 +280,16 @@ class ChipCluster:
 
     def popcounts(self, lp: lowering.LoweredProgram, sharded: RowState,
                   outputs: Sequence[str], mask_shards: jax.Array,
-                  backend: str = "scan") -> np.ndarray:
+                  backend: str = "scan") -> jax.Array:
         """Masked popcount of each output row, tree-psum'd across chips.
 
         `mask_shards` is the catalog tail mask pushed through
         `shard_words` (padding slots are all-zero there, so pad words
         never count); singleton axes are inserted so it broadcasts over
         any inner batch (query) axes. Returns ``(n_outputs,) + batch``
-        int counts — the only values that cross the chip boundary.
+        int counts — the only values that cross the chip boundary — as a
+        device array, still in flight: the caller's ``np.asarray`` is the
+        host sync.
 
         Traced like `run_lowered`; the span also records the tree-psum
         reduction depth (``psum_hops`` — recursive doubling over the chip
@@ -307,7 +309,7 @@ class ChipCluster:
 
     def _popcounts(self, lp: lowering.LoweredProgram, sharded: RowState,
                    outputs: Sequence[str], mask_shards: jax.Array,
-                   backend: str) -> np.ndarray:
+                   backend: str) -> jax.Array:
         names = tuple(sorted(sharded))
         shapes = tuple(tuple(sharded[k].shape) for k in names)
         sample_ndim = max(len(s) for s in shapes)
@@ -317,7 +319,7 @@ class ChipCluster:
         fn = self._sharded_vm(lp, names, tuple(outputs), shapes, backend,
                               mask_ndim=mask.ndim)
         counts = fn(tuple(sharded[k] for k in names), mask)
-        return np.asarray(jnp.stack(counts))
+        return jnp.stack(counts)
 
     def execute(self, program: Program, data: RowState,
                 outputs: Optional[List[str]] = None,

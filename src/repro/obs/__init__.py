@@ -22,10 +22,12 @@ from repro.obs.telemetry import (
     set_telemetry,
 )
 from repro.obs.trace import (
+    LEAF_SPANS,
     MODEL_PID,
     NULL_TRACER,
     WALL_PID,
     NullTracer,
+    Span,
     Tracer,
     validate_chrome_trace,
     write_chrome_trace,
@@ -43,10 +45,12 @@ __all__ = [
     "Telemetry",
     "get_telemetry",
     "set_telemetry",
+    "LEAF_SPANS",
     "MODEL_PID",
     "NULL_TRACER",
     "WALL_PID",
     "NullTracer",
+    "Span",
     "Tracer",
     "validate_chrome_trace",
     "write_chrome_trace",

@@ -4,9 +4,9 @@ Chrome trace-event JSON (loadable in `chrome://tracing` / Perfetto).
 Two clocks share one trace:
 
   * **wall events** (pid `WALL_PID`) — `begin`/`end`/`span` record real
-    `time.perf_counter` durations of serving stages (parse -> plan/cache ->
-    bind -> group -> dispatch -> readout), nested by stack discipline on
-    one thread track;
+    `time.perf_counter` durations of serving stages (tick -> plan ->
+    parse/plan_cache/bind -> group -> stack/launch/sync/readout), nested
+    by stack discipline on one thread track;
   * **modeled events** (pid `MODEL_PID`) — `model_event` places duration
     events on *virtual* tracks at modeled-nanosecond timestamps: the
     scheduler's per-chip bus / per-bank compute timeline, per-query
@@ -18,11 +18,21 @@ schema `validate_chrome_trace` enforces and tests/test_obs.py pins down);
 ``ts`` is microseconds as the trace-event spec requires, so modeled
 nanoseconds are divided by 1e3 on the way out.
 
-`NULL_TRACER` is the disabled twin: `tracing` is False and every method is
-a no-op. Instrumentation sites must guard anything that allocates (kwargs
+`NULL_TRACER` is the disabled twin: `tracing` is False and every method
+but `phase` is a no-op. Instrumentation sites must guard anything that allocates (kwargs
 dicts, f-strings) behind ``if tracer.tracing:`` so the disabled serving
 path stays allocation-free — the contract `benchmarks/obs_overhead.py`
 gates at < 3% overhead.
+
+The served path's phases are `Span`s (``tracer.phase(name, totals)``),
+on both tracers: each one enters a `jax.profiler.TraceAnnotation` of its
+name, so it lands on the profiler's host plane on the device trace's
+clock whenever a profile is collected; adds its `perf_counter` time to a
+caller-owned ``totals`` dict (the leaves); and, on a real `Tracer`, also
+records the Chrome B/E pair. The names are fixed (`TICK` ... `LEAF_SPANS`)
+and unique in a profile; the leaves never overlap, so their totals add.
+Off, a span costs one small object, the annotation and two clock reads
+(about 3 us on a CPU); sites pass it scalar args only.
 """
 from __future__ import annotations
 
@@ -30,12 +40,77 @@ import contextlib
 import json
 import pathlib
 import time
-from typing import Dict, List, Tuple, Union
+from typing import Dict, List, Optional, Tuple, Union
+
+from jax.profiler import TraceAnnotation
 
 WALL_PID = 1
 MODEL_PID = 2
 
 Json = Dict[str, Union[str, int, float, dict]]
+
+# The served path's spans. Parents: a serving-loop tick, from formation
+# to the end of its finalize; one plan group of a scheduler batch.
+TICK = "repro.tick"
+GROUP = "repro.group"
+# Leaves, in the order a tick runs them.
+TICK_FORM = "repro.tick.form"          # ServingLoop._form_tick
+TICK_PLAN = "repro.tick.plan"          # Scheduler.plan_queries
+GROUP_STACK = "repro.group.stack"      # the group's operand tensors
+GROUP_LAUNCH = "repro.group.launch"    # the executor call, to its return
+GROUP_SYNC = "repro.group.sync"        # host waits for counts / words
+GROUP_READOUT = "repro.group.readout"  # per-member scalars on the host
+TICK_ACCOUNT = "repro.tick.account"    # placement, results, totals
+TICK_FINALIZE = "repro.tick.finalize"  # ServingLoop._finalize
+LEAF_SPANS = (TICK_FORM, TICK_PLAN, GROUP_STACK, GROUP_LAUNCH, GROUP_SYNC,
+              GROUP_READOUT, TICK_ACCOUNT, TICK_FINALIZE)
+
+
+class Span:
+    """One phase of the served path: a profiler annotation, a host-time
+    total, and (on a real `Tracer`) a Chrome span, opened and closed by
+    one call each.
+
+    Use as ``with tracer.phase(name, totals):`` or, for a span whose end
+    lies in a later call, ``sp = tracer.phase(...).start()`` and later
+    ``sp.stop()``. `stop` returns the elapsed microseconds (also kept in
+    ``us``) and, when ``totals`` is given, adds them to
+    ``totals[name]``. The clock runs inside the annotation and the Chrome
+    pair, so a parent's time covers its leaves' and their overhead.
+    """
+
+    __slots__ = ("name", "us", "_totals", "_tracer", "_args", "_ann", "_t0")
+
+    def __init__(self, name: str, totals: Optional[Dict[str, float]],
+                 tracer: Optional["Tracer"], args: Optional[dict]):
+        self.name = name
+        self.us = 0.0
+        self._totals = totals
+        self._tracer = tracer
+        self._args = args
+
+    def start(self) -> "Span":
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        if self._tracer is not None:
+            self._tracer.begin(self.name, **self._args)
+        self._t0 = time.perf_counter()
+        return self
+
+    def stop(self) -> float:
+        self.us = (time.perf_counter() - self._t0) * 1e6
+        if self._totals is not None:
+            self._totals[self.name] = self._totals.get(self.name, 0.0) \
+                + self.us
+        if self._tracer is not None:
+            self._tracer.end()
+        self._ann.__exit__(None, None, None)
+        return self.us
+
+    __enter__ = start
+
+    def __exit__(self, *exc) -> None:
+        self.stop()
 
 
 class Tracer:
@@ -102,6 +177,11 @@ class Tracer:
         finally:
             self.end()
 
+    def phase(self, name: str, totals: Optional[Dict[str, float]] = None,
+              **args) -> Span:
+        """A served-path `Span`, recorded here with ``args`` as well."""
+        return Span(name, totals, self, args)
+
     def instant(self, name: str, **args) -> None:
         self.events.append({"name": name, "ph": "i", "ts": self._now_us(),
                             "pid": WALL_PID,
@@ -153,6 +233,11 @@ class NullTracer:
 
     def span(self, name: str, **args):
         return _NULL_CM
+
+    def phase(self, name: str, totals: Optional[Dict[str, float]] = None,
+              **args) -> Span:
+        """A served-path `Span`: annotation and ``totals`` only."""
+        return Span(name, totals, None, None)
 
     def instant(self, name: str, **args) -> None:
         pass

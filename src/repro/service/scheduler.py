@@ -74,6 +74,8 @@ from repro.core.bitplane import ROW_BITS
 from repro.core.compiler import Expr, compile_expr_fused
 from repro.core.timing import DDR3_1600, DramTiming
 from repro.obs.telemetry import set_telemetry
+from repro.obs.trace import (GROUP, GROUP_LAUNCH, GROUP_READOUT, GROUP_STACK,
+                             GROUP_SYNC, TICK_ACCOUNT)
 from repro.ops.popcount import popcount_words
 from repro.service.catalog import Catalog, plane_name
 from repro.service.optimizer import (CSE_PREFIX, CseBatch, CseExplain,
@@ -85,6 +87,13 @@ from repro.service.planner import (DST, ArithQuery, BoundPlan, Plan, Planner,
 POPCOUNT = "popcount"
 MATERIALIZE = "materialize"
 AGGREGATE = "aggregate"
+
+
+def _weighted_scalars(counts: np.ndarray, n_members: int) -> List[int]:
+    """Each member's weighted popcount sum_j 2**j * counts[j, member], in
+    exact Python ints, from the host's (n_outputs, n_members) counts."""
+    return [sum(int(c) << j for j, c in enumerate(counts[:, s]))
+            for s in range(n_members)]
 
 
 @dataclasses.dataclass
@@ -169,6 +178,10 @@ class BatchReport:
     n_cse_planes: int = 0
     total_aaps: int = 0
     baseline_aaps: int = 0
+    #: host microseconds of the batch's leaf spans, keyed by span name
+    #: (`repro.obs.trace`: the group leaves summed over every group, and
+    #: the accounting after them)
+    phase_us: Dict[str, float] = dataclasses.field(default_factory=dict)
 
     @property
     def qps(self) -> float:
@@ -224,6 +237,8 @@ class Scheduler:
         self.parity_checks = 0
         self.cse_planes_built = 0
         self._group_seq = 0      # deterministic per-dispatch PRNG chain
+        #: the leaf-span totals of the batch in flight (`BatchReport`)
+        self._phase_us: Dict[str, float] = {}
         if self.telemetry is None:
             from repro.obs.telemetry import NULL_TELEMETRY
 
@@ -243,7 +258,6 @@ class Scheduler:
             self._m_parity = m.counter("parity_checks_total")
             self._m_cse = m.counter("cse_planes_total")
             self._m_lat = m.histogram("modeled_latency_ns")
-            self._m_wall = m.histogram("batch_wall_us")
         if (self.reliability is not None
                 and self.reliability.mode != "none"
                 and self.cluster is not None):
@@ -306,12 +320,15 @@ class Scheduler:
         if self.cluster is not None:
             words, scalars = self._run_group_sharded(members, need_words)
             return words, scalars, 1
-        input_rows = [bp.input_map() for _, bp in members]
-        data = {
-            name: jnp.stack([self._operand_words(rows[name], cse_planes)
-                             for rows in input_rows])
-            for name in input_rows[0]
-        }
+        tr = self.telemetry.tracer
+        phases = self._phase_us
+        with tr.phase(GROUP_STACK, phases):
+            input_rows = [bp.input_map() for _, bp in members]
+            data = {
+                name: jnp.stack([self._operand_words(rows[name], cse_planes)
+                                 for rows in input_rows])
+                for name in input_rows[0]
+            }
         plan = members[0][1].plan
         # per-plan backend choice recorded by the optimizer wins over the
         # scheduler default (mitigated dispatch stays on the VM, where
@@ -327,47 +344,51 @@ class Scheduler:
             # on pallas — the planes never reach HBM) and only
             # (n_outputs, n_queries) int32 counts cross to the host, where
             # exact Python ints apply the 2**j aggregate weights.
-            opt = getattr(self.planner.cache, "optimizer", None)
-            if opt is not None:
-                backend = opt.backend(plan.program, fused_reduce=True)
-            self._count_dispatch(backend)
-            counts = lowering.execute_lowered(
-                plan.lowered, data, outputs=list(plan.outputs),
-                backend=backend, reduce="popcount",
-                mask=self.catalog.mask())
-            cnp = np.asarray(jnp.stack([counts[o] for o in plan.outputs]))
-            scalars = [sum(int(cnp[j, s]) << j
-                           for j in range(len(plan.outputs)))
-                       for s in range(len(members))]
+            with tr.phase(GROUP_LAUNCH, phases):
+                opt = getattr(self.planner.cache, "optimizer", None)
+                if opt is not None:
+                    backend = opt.backend(plan.program, fused_reduce=True)
+                self._count_dispatch(backend)
+                counts = lowering.execute_lowered(
+                    plan.lowered, data, outputs=list(plan.outputs),
+                    backend=backend, reduce="popcount",
+                    mask=self.catalog.mask())
+            with tr.phase(GROUP_SYNC, phases):
+                cnp = np.asarray(jnp.stack([counts[o]
+                                            for o in plan.outputs]))
+            with tr.phase(GROUP_READOUT, phases):
+                scalars = _weighted_scalars(cnp, len(members))
             return None, scalars, 1
-        if (rel is not None and rel.mode != "none"
-                and plan.lowered is not None):
-            backend = self.backend     # mitigation runs on the default VM
-            out, replicas = self._run_reliable(plan, data)
-        elif backend == "interp":
-            # degenerate 1-2 command programs: eager micro-op interpreter,
-            # a VM launch would cost more than the program
-            out = engine.execute(plan.program, data,
-                                 outputs=list(plan.outputs), lowered=False)
-        elif plan.lowered is not None:
-            out = lowering.execute_lowered(
-                plan.lowered, data, outputs=list(plan.outputs),
-                backend=backend)
-        else:   # plans built outside the cache fall back to the engine
-            backend = self.backend
-            out = engine.execute(plan.program, data,
-                                 outputs=list(plan.outputs),
-                                 backend=self.backend)
-        self._count_dispatch(backend)
-        mask = self.catalog.mask()
-        # (n_outputs, len(members), n_words), output planes LSB-first
-        masked = jnp.stack([out[o] & mask for o in plan.outputs])
-        counts = np.asarray(popcount_words(masked, axis=-1))
-        scalars = [sum(int(counts[j, s]) << j
-                       for j in range(len(plan.outputs)))
-                   for s in range(len(members))]
-        words = (np.asarray(jnp.moveaxis(masked, 0, 1))
-                 if need_words else None)
+        with tr.phase(GROUP_LAUNCH, phases):
+            if (rel is not None and rel.mode != "none"
+                    and plan.lowered is not None):
+                backend = self.backend  # mitigation runs on the default VM
+                out, replicas = self._run_reliable(plan, data)
+            elif backend == "interp":
+                # degenerate 1-2 command programs: eager micro-op
+                # interpreter, a VM launch would cost more than the program
+                out = engine.execute(plan.program, data,
+                                     outputs=list(plan.outputs),
+                                     lowered=False)
+            elif plan.lowered is not None:
+                out = lowering.execute_lowered(
+                    plan.lowered, data, outputs=list(plan.outputs),
+                    backend=backend)
+            else:   # plans built outside the cache fall back to the engine
+                backend = self.backend
+                out = engine.execute(plan.program, data,
+                                     outputs=list(plan.outputs),
+                                     backend=self.backend)
+            self._count_dispatch(backend)
+        with tr.phase(GROUP_SYNC, phases):
+            mask = self.catalog.mask()
+            # (n_outputs, len(members), n_words), output planes LSB-first
+            masked = jnp.stack([out[o] & mask for o in plan.outputs])
+            counts = np.asarray(popcount_words(masked, axis=-1))
+            words = (np.asarray(jnp.moveaxis(masked, 0, 1))
+                     if need_words else None)
+        with tr.phase(GROUP_READOUT, phases):
+            scalars = _weighted_scalars(counts, len(members))
         return words, scalars, replicas
 
     def _run_reliable(self, plan: Plan, data: Dict[str, jax.Array]
@@ -482,12 +503,15 @@ class Scheduler:
         shards; materialize gathers the output rows once per group.
         """
         cluster = self.cluster
-        input_rows = [bp.input_map() for _, bp in members]
-        data = {
-            name: jnp.stack([self.catalog.shards(rows[name])
-                             for rows in input_rows], axis=2)
-            for name in input_rows[0]
-        }
+        tr = self.telemetry.tracer
+        phases = self._phase_us
+        with tr.phase(GROUP_STACK, phases):
+            input_rows = [bp.input_map() for _, bp in members]
+            data = {
+                name: jnp.stack([self.catalog.shards(rows[name])
+                                 for rows in input_rows], axis=2)
+                for name in input_rows[0]
+            }
         plan = members[0][1].plan
         # shard_map dispatch needs a lowered VM: honor the optimizer's
         # backend only when it is one ("interp" falls back to the default)
@@ -500,30 +524,33 @@ class Scheduler:
         if not need_words:
             # scalar-only group: one shard_map launch, only the count
             # matrix crosses the chip boundary
-            counts = cluster.popcounts(lp, data, plan.outputs,
-                                       self.catalog.mask_shards(),
-                                       backend=backend)
-            return None, [sum(int(counts[j, s]) << j
-                              for j in range(len(plan.outputs)))
-                          for s in range(len(members))]
+            with tr.phase(GROUP_LAUNCH, phases):
+                counts = cluster.popcounts(lp, data, plan.outputs,
+                                           self.catalog.mask_shards(),
+                                           backend=backend)
+            with tr.phase(GROUP_SYNC, phases):
+                counts = np.asarray(counts)
+            with tr.phase(GROUP_READOUT, phases):
+                return None, _weighted_scalars(counts, len(members))
         # materialize group: the output rows must be gathered anyway, so
         # run ONCE and derive the counts from the gathered masked planes
         # (exactly as the single-process twin does)
-        out = cluster.run_lowered(lp, data, plan.outputs,
-                                  backend=backend)
-        n_words = self.catalog.get(
-            next(iter(input_rows[0].values()))).words.shape[0]
-        mask = self.catalog.mask()
-        # (n_outputs, len(members), n_words) -> query-major, as in the
-        # single-process path
-        masked = jnp.stack(
-            [cluster.unshard_words(out[o], int(n_words)) & mask
-             for o in plan.outputs])
-        counts = np.asarray(popcount_words(masked, axis=-1))
-        scalars = [sum(int(counts[j, s]) << j
-                       for j in range(len(plan.outputs)))
-                   for s in range(len(members))]
-        return np.asarray(jnp.moveaxis(masked, 0, 1)), scalars
+        with tr.phase(GROUP_LAUNCH, phases):
+            out = cluster.run_lowered(lp, data, plan.outputs,
+                                      backend=backend)
+        with tr.phase(GROUP_SYNC, phases):
+            n_words = self.catalog.get(
+                next(iter(input_rows[0].values()))).words.shape[0]
+            mask = self.catalog.mask()
+            # (n_outputs, len(members), n_words) -> query-major, as in the
+            # single-process path
+            masked = jnp.stack(
+                [cluster.unshard_words(out[o], int(n_words)) & mask
+                 for o in plan.outputs])
+            counts = np.asarray(popcount_words(masked, axis=-1))
+            words = np.asarray(jnp.moveaxis(masked, 0, 1))
+        with tr.phase(GROUP_READOUT, phases):
+            return words, _weighted_scalars(counts, len(members))
 
     # -- the scheduler proper ------------------------------------------------
 
@@ -556,7 +583,6 @@ class Scheduler:
         tel = self.telemetry
         if not (tel.tracing or tel.metering):
             return self._submit(queries, tel, preplanned, allow_cse)
-        wall0 = time.perf_counter()
         if tel.tracing:
             tr = tel.tracer
             # core layers (engine / bankgroup / cluster) have no handle on
@@ -575,7 +601,6 @@ class Scheduler:
             self._m_batches.inc()
             self._m_groups.inc(report.n_plan_groups)
             self._m_modeled_ns.inc(report.makespan_ns)
-            self._m_wall.observe((time.perf_counter() - wall0) * 1e6)
         return report
 
     def _submit(self, queries: Sequence[Query],
@@ -584,6 +609,7 @@ class Scheduler:
                 allow_cse: bool = True) -> BatchReport:
         tracing = tel.tracing
         tr = tel.tracer
+        phases = self._phase_us = {}
         if self.reliability is not None and self.reliability.mode == "ecc":
             # ecc mode opens every batch with a catalog integrity probe:
             # the maintained per-group XOR parity must match a fresh
@@ -640,37 +666,47 @@ class Scheduler:
         groups: Dict[Tuple, List[Tuple[int, BoundPlan]]] = {}
         for idx, bp in enumerate(bound):
             groups.setdefault(bp.plan.key, []).append((idx, bp))
-        words_by_idx: Dict[int, np.ndarray] = {}
-        count_by_idx: Dict[int, int] = {}
-        replicas_by_idx: Dict[int, int] = {}
         dispatch = (self._run_group_resilient
                     if self.fault_tolerance is not None else self._run_group)
+        ran = []
         for members in groups.values():
             need_words = any(queries[idx].mode == MATERIALIZE
                              for idx, _ in members)
-            if tracing:
-                tr.begin("group", members=[idx for idx, _ in members],
-                         n_aaps=members[0][1].plan.n_aaps)
-                tr.begin("dispatch")
-            stacked, scalars, replicas = dispatch(members, need_words,
-                                                  cse_planes)
-            if tracing:
-                tr.end()
-                tr.begin("readout")
-            plan = members[0][1].plan
+            with tr.phase(GROUP, None, n_queries=len(members),
+                          n_aaps=members[0][1].plan.n_aaps):
+                ran.append((members,
+                            *dispatch(members, need_words, cse_planes)))
+
+        with tr.phase(TICK_ACCOUNT, phases):
+            report = self._account(queries, orig_bound, bound, cse, ran, tel)
+        report.phase_us = phases
+        return report
+
+    def _account(self, queries: Sequence[Query],
+                 orig_bound: List[BoundPlan], bound: List[BoundPlan],
+                 cse: Optional[CseBatch], ran: list,
+                 tel: "Telemetry") -> BatchReport:  # noqa: F821
+        """A batch's results and costs from its groups' host values.
+
+        ``ran`` holds each group's (members, words, scalars, replicas) as
+        `_run_group` returned them.
+        """
+        tracing = tel.tracing
+        tr = tel.tracer
+        words_by_idx: Dict[int, np.ndarray] = {}
+        count_by_idx: Dict[int, int] = {}
+        replicas_by_idx: Dict[int, int] = {}
+        for members, stacked, scalars, replicas in ran:
             # boolean plans (single DST row) materialize as a flat word
             # vector; arithmetic plans as the (n_outputs, n_words) plane
             # stack — even at width 1, so plane shapes stay stable
-            is_boolean = plan.outputs == (DST,)
+            is_boolean = members[0][1].plan.outputs == (DST,)
             for slot, (idx, _) in enumerate(members):
                 if stacked is not None:
                     w = stacked[slot]          # (n_outputs, n_words)
                     words_by_idx[idx] = w[0] if is_boolean else w
                 count_by_idx[idx] = scalars[slot]
                 replicas_by_idx[idx] = replicas
-            if tracing:
-                tr.end()    # readout
-                tr.end()    # group
 
         # 3. modeled timeline (`_place_batch`): shared planes first, then
         #    queries on least-loaded (chip, bank) slots; a consumer cannot
@@ -726,7 +762,6 @@ class Scheduler:
                 self._m_lat.observe(lat)
                 self._m_aaps.inc((bp.plan.n_aaps + extra_aaps)
                                  * n_blocks * replicas)
-                self._m_energy.inc(energy)
                 if q.tenant is not None:
                     m = tel.metrics
                     m.counter("tenant_queries_total",
@@ -747,15 +782,21 @@ class Scheduler:
                                self.timing.aap_ns, "reduce", hop=h)
         self.queries_served += len(queries)
         self.total_modeled_ns += makespan
-        self.total_energy_nj += sum(r.energy_nj for r in results)
+        # one sum feeds both totals, so the registry and the legacy
+        # attribute agree to the last bit
+        energy_nj = sum(r.energy_nj for r in results)
+        self.total_energy_nj += energy_nj
+        if tel.metering:
+            self._m_energy.inc(energy_nj)
         return BatchReport(
-            results, makespan, self.n_banks, len(groups), n_chips=n_chips,
+            results, makespan, self.n_banks, len(ran), n_chips=n_chips,
             n_cse_planes=(len(cse.defs) if cse is not None else 0),
             total_aaps=n_blocks * (def_aaps
                                    + sum(bp.plan.n_aaps for bp in bound)),
             baseline_aaps=n_blocks * sum(
                 (bp.plan.n_aaps_unopt if bp.plan.n_aaps_unopt is not None
                  else bp.plan.n_aaps) for bp in orig_bound))
+
 
     # -- optimize: batch-level sharing + modeled placement -------------------
 

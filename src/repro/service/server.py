@@ -35,7 +35,10 @@ item 1, in the style of vLLM's TPU worker) built from four pieces:
 
 Everything is instrumented through the PR 7 telemetry layer: queue-depth
 gauge, shed/deferred counters, per-tick occupancy histogram, tick spans
-plus queue-depth counter samples in the Chrome trace.
+plus queue-depth counter samples in the Chrome trace. Every tick, live
+or replayed, runs under the `repro.tick` span with its host phases as
+leaf spans (`repro.obs.trace`): on the profiler's clock in any
+`jax.profiler` trace, and summed per tick into `TickStats.phase_us`.
 
 Two clocks, as everywhere in this repo: `run_trace` replays an arrival
 trace in *modeled* nanoseconds (DDR3 AAP timing — deterministic,
@@ -54,6 +57,8 @@ import time
 from collections import OrderedDict, deque
 from typing import Deque, Dict, List, Optional, Sequence, Tuple, Union
 
+from repro.obs.trace import (TICK, TICK_FINALIZE, TICK_FORM, TICK_PLAN,
+                             Span)
 from repro.service.config import DEFER, OBSERVE, SHED, SloConfig
 from repro.service.scheduler import POPCOUNT, Query, QueryResult
 
@@ -170,8 +175,18 @@ class TickStats:
     n_groups: int                     # distinct plan shapes packed
     occupancy: float                  # n_queries / capacity
     queue_depth: int                  # left queued after formation
-    plan_wall_us: float = 0.0
-    exec_wall_us: float = 0.0
+    #: host microseconds of the tick's leaf spans, keyed by span name
+    #: (`repro.obs.trace.LEAF_SPANS`; the group leaves summed over the
+    #: tick's groups); they never overlap
+    phase_us: Dict[str, float] = dataclasses.field(default_factory=dict)
+    #: host microseconds of the whole `repro.tick` span, formation to the
+    #: end of `_finalize`: what the leaves leave uncovered is the rest
+    wall_us: float = 0.0
+
+    @property
+    def plan_wall_us(self) -> float:
+        """Host microseconds planning the tick (`repro.tick.plan`)."""
+        return self.phase_us.get(TICK_PLAN, 0.0)
 
 
 @dataclasses.dataclass
@@ -267,13 +282,14 @@ class _Done:
 
 @dataclasses.dataclass
 class _Inflight:
-    future: object                    # Future[(BatchReport, exec_wall_us)]
+    future: object                    # Future[BatchReport]
     batch: List[_Item]
     start_ns: float                   # exact: device was free at launch
     form_ns: float
     est_free_ns: float                # projected completion (EMA)
-    plan_wall_us: float
     tick: int
+    span: Span                        # the open `repro.tick` span
+    phases: Dict[str, float]          # the tick's leaf totals so far
 
 
 class ServingLoop:
@@ -507,6 +523,22 @@ class ServingLoop:
 
     # -- dispatch ------------------------------------------------------------
 
+    def _form_and_plan(self, now_ns: float, can_defer: bool):
+        """Host stage of a tick: open its `repro.tick` span, form it and
+        plan it. Returns (batch, bound plans, tick span, leaf totals); an
+        empty formation closes its span and returns no plans."""
+        tr = self.telemetry.tracer
+        span = tr.phase(TICK, None, tick=self._tick_seq).start()
+        phases: Dict[str, float] = {}
+        with tr.phase(TICK_FORM, phases):
+            batch = self._form_tick(now_ns, can_defer)
+        if not batch:
+            span.stop()
+            return batch, None, span, phases
+        with tr.phase(TICK_PLAN, phases):
+            bound = self.scheduler.plan_queries([it.query for it in batch])
+        return batch, bound, span, phases
+
     def _execute(self, queries: List[Query], bound) -> object:
         """Device stage: one preplanned scheduler dispatch.
 
@@ -520,26 +552,36 @@ class ServingLoop:
                                          allow_cse=False)
 
     def _launch(self, batch: List[_Item], bound, form_ns: float,
-                plan_us: float, pool) -> _Inflight:
+                span: Span, phases: Dict[str, float], pool) -> _Inflight:
         start = max(self._device_free, form_ns)
         tick = self._tick_seq
         self._tick_seq += 1
         for it in batch:
             it.tick = tick
         queries = [it.query for it in batch]
-
-        def run():
-            w0 = time.perf_counter()
-            rep = self._execute(queries, bound)
-            return rep, (time.perf_counter() - w0) * 1e6
-
-        fut = pool.submit(run) if pool is not None else _Done(run())
+        if pool is not None:
+            fut = pool.submit(self._execute, queries, bound)
+        else:
+            fut = _Done(self._execute(queries, bound))
         est = self._est_query_ns or 0.0
         return _Inflight(fut, batch, start, form_ns,
-                         start + est * len(batch), plan_us, tick)
+                         start + est * len(batch), tick, span, phases)
 
     def _finalize(self, fl: _Inflight) -> None:
-        rep, exec_us = fl.future.result()
+        """Close a tick: resolve its handles and record its `TickStats`.
+
+        The wait for a pipelined tick's device stage lies outside the
+        `repro.tick.finalize` leaf, so the leaves never overlap the
+        scheduler's, which ran on the worker meanwhile."""
+        rep = fl.future.result()
+        fl.phases.update(rep.phase_us)
+        with self.telemetry.tracer.phase(TICK_FINALIZE, fl.phases):
+            stats = self._record_tick(fl, rep)
+        stats.wall_us = fl.span.stop()
+        if self.on_tick is not None:
+            self.on_tick(stats)
+
+    def _record_tick(self, fl: _Inflight, rep) -> TickStats:
         self._device_free = fl.start_ns + rep.makespan_ns
         per_q = rep.makespan_ns / max(1, len(fl.batch))
         if self._est_query_ns is None:
@@ -552,11 +594,8 @@ class ServingLoop:
             tick=fl.tick, form_ns=fl.form_ns, start_ns=fl.start_ns,
             makespan_ns=rep.makespan_ns, n_queries=len(fl.batch),
             n_groups=rep.n_plan_groups, occupancy=occupancy,
-            queue_depth=self._n_queued, plan_wall_us=fl.plan_wall_us,
-            exec_wall_us=exec_us)
+            queue_depth=self._n_queued, phase_us=fl.phases)
         self._ticks.append(stats)
-        if self.on_tick is not None:
-            self.on_tick(stats)
         tel = self.telemetry
         for it, r in zip(fl.batch, rep.results):
             complete = fl.start_ns + r.latency_ns
@@ -582,6 +621,7 @@ class ServingLoop:
                            occupancy=occupancy)
             tr.counter_event("serve_queue_depth", fl.start_ns,
                              "serve/queue", depth=self._n_queued)
+        return stats
 
     # -- deterministic trace replay ------------------------------------------
 
@@ -613,8 +653,6 @@ class ServingLoop:
         wall0 = time.perf_counter()
         prev: Optional[_Inflight] = None
         min_now = 0.0
-        tr = self.telemetry.tracer
-        tracing = self.telemetry.tracing
         try:
             while pending or self._n_queued or prev is not None:
                 est_free = (prev.est_free_ns if prev is not None
@@ -625,38 +663,26 @@ class ServingLoop:
                 if pending:
                     cands.append(pending[0].arrival_ns)
                 batch: List[_Item] = []
-                bound = None
-                now = plan_us = 0.0
+                now = 0.0
                 if cands:
                     now = max(est_free, min(cands), min_now)
                     while pending and pending[0].arrival_ns <= now:
                         self._admit(pending.popleft())
                     can_defer = bool(pending) or prev is not None
-                    batch = self._form_tick(now, can_defer)
-                    if batch:
-                        if tracing:
-                            tr.begin("tick", tick=self._tick_seq,
-                                     n_queries=len(batch))
-                            tr.begin("tick_plan")
-                        w0 = time.perf_counter()
-                        # host stage of the double buffer: overlapped
-                        # with `prev` still executing on the worker
-                        bound = self.scheduler.plan_queries(
-                            [it.query for it in batch])
-                        plan_us = (time.perf_counter() - w0) * 1e6
-                        if tracing:
-                            tr.end()    # tick_plan
+                    # host stage of the double buffer: overlapped with
+                    # `prev` still executing on the worker
+                    batch, bound, span, phases = self._form_and_plan(
+                        now, can_defer)
                 if prev is not None:
                     self._finalize(prev)
                     prev = None
                 if batch:
                     min_now = 0.0
-                    prev = self._launch(batch, bound, now, plan_us, pool)
+                    prev = self._launch(batch, bound, now, span, phases,
+                                        pool)
                     if pool is None:
                         self._finalize(prev)
                         prev = None
-                    if tracing:
-                        tr.end()        # tick
                 elif cands and pending:
                     # nothing eligible at `now`: the next attempt must
                     # see new work, or it would spin on the same state
@@ -729,22 +755,21 @@ class ServingLoop:
                 # runs on wall nanoseconds (the EMA and projections stay
                 # unit-consistent because ticks are finalized on wall
                 # time below)
-                batch = self._form_tick(now, can_defer=not stopping)
+                batch, bound, span, phases = self._form_and_plan(
+                    now, can_defer=not stopping)
                 if not batch:
                     continue
-                bound = self.scheduler.plan_queries(
-                    [it.query for it in batch])
-                fl = self._launch(batch, bound, now, 0.0, None)
+                fl = self._launch(batch, bound, now, span, phases, None)
                 # overwrite modeled bookkeeping with wall: device is
                 # free when the dispatch actually returned
-                rep, exec_us = fl.future.result()
+                rep = fl.future.result()
                 end_ns = self._wall_ns()
                 fl.start_ns = now
                 wall_makespan = max(end_ns - now, 1.0)
                 rep = dataclasses.replace(rep, makespan_ns=wall_makespan)
                 for r in rep.results:
                     r.latency_ns = wall_makespan
-                fl.future = _Done((rep, exec_us))
+                fl.future = _Done(rep)
                 self._finalize(fl)
         except BaseException as e:  # noqa: BLE001 - fail pending handles
             self._live_error = e

@@ -257,11 +257,30 @@ def test_trace_spans_cover_every_query(traced_run):
     assert b_names.count("batch") == 1
     assert b_names.count("query") == len(queries)
     assert b_names.count("parse") + b_names.count("plan_cache") > 0
-    # plan-group dispatch/readout spans appear once per group
+    # plan-group launch/readout spans appear once per group; each shared
+    # plane's dispatch (under cse_group) has its own
     report = traced_run[2]
-    assert b_names.count("group") == report.n_plan_groups
-    assert b_names.count("dispatch") == report.n_plan_groups
-    assert b_names.count("readout") == report.n_plan_groups
+    assert b_names.count("repro.group") == report.n_plan_groups
+    in_group = _names_under(events, "repro.group")
+    assert in_group.count("repro.group.launch") == report.n_plan_groups
+    assert in_group.count("repro.group.readout") == report.n_plan_groups
+    in_cse = _names_under(events, "cse_group")
+    assert in_cse.count("repro.group.launch") == report.n_cse_planes
+    assert in_cse.count("repro.group.readout") == report.n_cse_planes
+
+
+def _names_under(events, parent):
+    """Names of the wall-clock B events opened while a ``parent`` span is
+    open on the same track."""
+    stack, out = [], []
+    for e in events:
+        if e["ph"] == "B":
+            if parent in stack:
+                out.append(e["name"])
+            stack.append(e["name"])
+        elif e["ph"] == "E":
+            stack.pop()
+    return out
 
 
 def test_trace_modeled_latencies_match_batch_report(traced_run):

@@ -9,6 +9,11 @@ reordered within a tenant). The redesigned service surface
 (ServiceConfig, submit/flush, deprecation shims) is tested at the
 bottom.
 """
+import glob
+import os
+import subprocess
+import sys
+import textwrap
 import threading
 
 import jax
@@ -17,14 +22,16 @@ import pytest
 
 from repro.dist.fault_tolerance import (ChipFailure, FaultTolerance,
                                         SimulatedFailure)
-from repro.obs import Telemetry
-from repro.obs.trace import validate_chrome_trace
+from repro.obs import NULL_TELEMETRY, Telemetry
+from repro.obs.trace import (GROUP, LEAF_SPANS, TICK, TICK_PLAN,
+                             validate_chrome_trace)
 from repro.service import (DEFER, MATERIALIZE, Arrival, Query, QueryHandle,
                            QueryService, QueryShedError, ServiceConfig,
                            SloConfig, results_bit_identical,
                            run_queries_unbatched)
 
 N_DEV = len(jax.devices())
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 multichip = pytest.mark.skipif(
     N_DEV < 2,
@@ -348,7 +355,7 @@ def test_loop_trace_and_metrics():
     payload = tel.tracer.export()
     validate_chrome_trace(payload)
     names = [e["name"] for e in payload["traceEvents"]]
-    assert "tick" in names and "tick_plan" in names
+    assert "repro.tick" in names and "repro.tick.plan" in names
     counters = [e for e in payload["traceEvents"] if e["ph"] == "C"]
     assert counters and all(e["name"] == "serve_queue_depth"
                             for e in counters)
@@ -359,6 +366,112 @@ def test_loop_trace_and_metrics():
     s = svc.stats()
     assert s["serve_ticks"] == len(rep.ticks)
     assert "serve_queue_depth" in s
+
+
+# ---------------------------------------------------------------------------
+# host phases: the repro.* spans and TickStats.phase_us
+# ---------------------------------------------------------------------------
+
+
+def _live(svc, n=12):
+    """Serve ``n`` queries through a started loop; the loop's report."""
+    loop = svc.serve_loop(depth=2)
+    loop.start()
+    handles = [loop.submit(EXPRS[i % len(EXPRS)], tenant=f"t{i % 2}")
+               for i in range(n)]
+    for h in handles:
+        h.result(timeout=120)
+    return loop.stop()
+
+
+def test_live_ticks_carry_phase_totals():
+    rep = _live(_service())
+    assert rep.ticks
+    for t in rep.ticks:
+        assert set(t.phase_us) == set(LEAF_SPANS)
+        assert t.plan_wall_us > 0
+        assert t.plan_wall_us == t.phase_us[TICK_PLAN]
+        leaves = sum(t.phase_us.values())
+        assert 0.9 * t.wall_us <= leaves <= t.wall_us, (t.phase_us,
+                                                         t.wall_us)
+
+
+def test_replayed_ticks_carry_phase_totals():
+    for pipeline in (False, True):
+        rep = _service().serve_loop(depth=2).run_trace(
+            _trace(12, spacing_ns=0.0), pipeline=pipeline)
+        for t in rep.ticks:
+            assert set(t.phase_us) == set(LEAF_SPANS)
+            assert t.plan_wall_us == t.phase_us[TICK_PLAN] > 0
+            assert sum(t.phase_us.values()) <= t.wall_us
+
+
+def test_phase_spans_on_the_profiler_host_plane(tmp_path):
+    svc = _service()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _live(svc, 4)
+    finally:
+        jax.profiler.stop_trace()
+    files = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    assert len(files) == 1
+    data = jax.profiler.ProfileData.from_file(files[0])
+    names = {e.name for plane in data.planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events}
+    assert {TICK, GROUP, *LEAF_SPANS} <= names
+
+
+def test_null_telemetry_keeps_phases_and_records_no_chrome_events():
+    svc = _service(telemetry=NULL_TELEMETRY)
+    rep = _live(svc, 4)
+    assert svc.telemetry.tracer.events == []
+    assert all(set(t.phase_us) == set(LEAF_SPANS) for t in rep.ticks)
+
+
+def test_sharded_groups_carry_phase_totals_subprocess():
+    """The 4-chip `shard_map` group path on 4 virtual CPU devices: both
+    its branches (count-only and materialize) time the same group
+    leaves, and answer as one chip does."""
+    code = textwrap.dedent(f"""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import sys; sys.path.insert(0, {REPO!r} + "/src")
+        import numpy as np
+        from repro.obs.trace import LEAF_SPANS
+        from repro.service import (MATERIALIZE, POPCOUNT, Query,
+                                   QueryService, ServiceConfig,
+                                   results_bit_identical)
+
+        def build(n_chips):
+            svc = QueryService(ServiceConfig(n_banks=2, n_chips=n_chips))
+            rng = np.random.default_rng(11)
+            for n in "abcd":
+                svc.register_bits(n, rng.integers(0, 2, 640).astype(bool),
+                                  group="t")
+            return svc
+
+        queries = [Query("a & b", POPCOUNT), Query("c & d", POPCOUNT),
+                   Query("a | c", MATERIALIZE), Query("b | d", MATERIALIZE)]
+        svc = build(4)
+        assert svc.scheduler.cluster.n_chips == 4
+        loop = svc.serve_loop(depth=2)
+        loop.start()
+        handles = [loop.submit(q) for q in queries]
+        got = [h.result(timeout=300) for h in handles]
+        rep = loop.stop()
+        want = build(None).query_batch(queries).results
+        assert results_bit_identical(got, want)
+        assert rep.ticks
+        for t in rep.ticks:
+            assert set(t.phase_us) == set(LEAF_SPANS), t.phase_us
+            assert sum(t.phase_us.values()) <= t.wall_us
+        print("SHARDED_PHASES_OK")
+    """)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=600, env=env)
+    assert "SHARDED_PHASES_OK" in r.stdout, r.stderr[-2000:]
 
 
 # ---------------------------------------------------------------------------
