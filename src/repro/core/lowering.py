@@ -61,6 +61,7 @@ import numpy as np
 from repro.core.addressing import D_WL, resolve
 from repro.core.commands import AAP, AP, Program
 from repro.core.engine import BuddyError
+from repro.kernels.common import SUBLANE, round_up
 
 # Fixed plane layout: the 8 B/C-group rows, then the write sink, then
 # D-group rows in first-reference order.
@@ -392,10 +393,11 @@ class _Layout:
 
     Plane rows are renumbered so the seeded data rows form one contiguous
     block right after the reserved rows and the output rows coalesce into
-    as few contiguous runs as possible. That makes the dispatch jaxpr
-    gather-free: plane build is a 3-piece concatenate, output extraction a
-    handful of static slices — the compile cost of the whole dispatch is
-    the scan body plus O(1) glue, however many operand planes there are.
+    as few contiguous runs as possible. That keeps the plane build one
+    operation — a 3-piece concatenate of loose rows, or one gather of a
+    `Gather`'s slots — and output extraction a handful of static slices:
+    the compile cost of the whole dispatch is the scan body plus O(1)
+    glue, however many operand planes there are.
     """
 
     table: np.ndarray               # opcode table over renumbered rows
@@ -450,6 +452,78 @@ def _layout(lp: LoweredProgram, data_names: Tuple[str, ...],
     return layout
 
 
+@dataclasses.dataclass(frozen=True, eq=False)
+class Gather:
+    """Operand rows named by slot in one device-resident source table.
+
+    Row ``name`` of the dispatch is ``source[rows[name], 0]``: ``rows``
+    maps each name to an int array of slots whose shape is the plane's
+    batch shape (one slot per bank or query). The source's unit axis
+    keeps each row one contiguous run in a TPU's tiled layout
+    (`kernels.gather`). ``zero`` and ``ones`` are slots holding all-zero
+    and all-one words, which the reserved rows, C1 and the zero tail are
+    gathered from — so the whole plane tensor is one gather inside the
+    dispatch, and no operand is copied on the host.
+    """
+
+    source: jax.Array               # (n_slots, 1, row_words) uint32
+    rows: Dict[str, np.ndarray]     # row name -> batch-shaped int slots
+    zero: int
+    ones: int
+
+    @property
+    def batch(self) -> Tuple[int, ...]:
+        return tuple(np.broadcast_shapes(
+            *(np.shape(v) for v in self.rows.values())))
+
+    def index(self, lay: "_Layout") -> np.ndarray:
+        """The int32 slot table of ``lay``'s plane, ``(n_rows, *batch)``
+        with ``n_rows`` rounded up to a multiple of 8: rows past the
+        program's are zero and never addressed, and a whole number of
+        8-row tiles is what the megakernel reads (`_take`)."""
+        batch = self.batch
+        n_rows = round_up(lay.n_rows, SUBLANE)
+        idx = np.full((n_rows,) + batch, self.zero, np.int32)
+        idx[C1_IDX] = self.ones
+        for i, n in enumerate(FIXED_ROWS):      # rare: seeded fixed rows
+            if n in self.rows:
+                idx[i] = self.rows[n]
+        for k, n in enumerate(lay.val_names):
+            idx[N_RESERVED + k] = self.rows[n]
+        return idx
+
+    def loose(self, names=None) -> Dict[str, jax.Array]:
+        """``{name: (*batch, row_words) words}``: the rows themselves, in
+        one gather, for executors that take a dict of rows."""
+        names = list(self.rows if names is None else names)
+        batch = self.batch
+        idx = np.stack([np.broadcast_to(self.rows[n], batch)
+                        for n in names]).astype(np.int32)
+        block = _take_jit(self.source, jnp.asarray(idx))
+        return {n: block[k] for k, n in enumerate(names)}
+
+
+def _take(source: jax.Array, idx: jax.Array) -> jax.Array:
+    """``source[idx, 0]``: the ``(*idx.shape, row_words)`` rows of a
+    ``(n_slots, 1, row_words)`` source named by ``idx`` (`kernels.gather`).
+
+    The rows are gathered batch-major, the order the megakernel reads
+    (`kernels.vm`), so with a multiple of 8 rows the axis moves between
+    here and the kernel cancel and the plane reaches it with no copy.
+    """
+    from repro.kernels.gather import gather_rows
+
+    flat = jnp.moveaxis(idx, 0, -1).reshape(-1)
+    n = flat.shape[0]
+    flat = jnp.pad(flat, (0, -n % SUBLANE))
+    rows = gather_rows(source, flat)[:n]
+    rows = rows.reshape(idx.shape[1:] + idx.shape[:1] + source.shape[-1:])
+    return jnp.moveaxis(rows, -2, 0)
+
+
+_take_jit = jax.jit(_take)
+
+
 def weight_counts(counts: jax.Array) -> jax.Array:
     """``sum_j 2**j * counts[j]`` over the leading plane axis, in float32.
 
@@ -467,18 +541,24 @@ def weight_counts(counts: jax.Array) -> jax.Array:
 @functools.partial(jax.jit, static_argnames=(
     "n_rows", "out_runs", "row_words", "batch", "backend", "fixed_idx",
     "reduce"))
-def _dispatch(table, vals, fixed_vals=(), errors=None, mask=None, *, n_rows,
-              out_runs, row_words, batch, backend, fixed_idx=(), reduce=None):
+def _dispatch(table, vals, fixed_vals=(), errors=None, mask=None,
+              source=None, idx=None, *, n_rows, out_runs, row_words, batch,
+              backend, fixed_idx=(), reduce=None):
     """Plane build + VM run + output extraction as ONE compiled dispatch.
 
     The opcode table is a *traced* argument, so the compiled executable is
     shared by every program whose shapes and layout counts match — only
     ``(n_cmds, n_rows, words)`` and the static slice boundaries key the
-    jit cache, not program structure. Thanks to `_Layout` renumbering the
-    body is gather-free: concatenate [reserved rows | stacked operand
-    planes | zero tail], scan (or megakernel), slice the output runs.
-    ``errors`` (also traced; None on the clean path) carries the
-    per-command TRA fault masks of `core.errors` into the VM.
+    jit cache, not program structure. The plane comes from one of two
+    sources. Loose rows (``vals``, thanks to `_Layout` renumbering):
+    concatenate [reserved rows | stacked operand planes | zero tail]. A
+    source table (``source``, with the traced ``(n_rows, *batch)`` slot
+    table ``idx``, `Gather`): one gather, ``source[idx, 0]``
+    (`kernels.gather`), so which catalog rows a group reads never keys
+    the cache either. Then scan (or megakernel) and slice the output
+    runs. ``errors`` (also traced; None
+    on the clean path) carries the per-command TRA fault masks of
+    `core.errors` into the VM.
 
     ``reduce`` (static) selects the fused count epilogue: instead of the
     output rows, return their per-plane masked popcounts (``"popcount"``,
@@ -489,16 +569,20 @@ def _dispatch(table, vals, fixed_vals=(), errors=None, mask=None, *, n_rows,
     a reduce mode) ANDs a per-word mask into every counted row.
     """
     shape = batch + (row_words,)
-    tail = n_rows - N_RESERVED - len(vals)
-    if vals:
-        block = jnp.concatenate(
-            [jnp.broadcast_to(v, (1,) + shape) for v in vals])
-        plane = jnp.pad(block, ((N_RESERVED, tail),) + ((0, 0),) * len(shape))
+    if source is not None:
+        plane = _take(source, idx)
     else:
-        plane = jnp.zeros((n_rows,) + shape, jnp.uint32)
-    plane = plane.at[C1_IDX].set(jnp.full(shape, 0xFFFFFFFF, jnp.uint32))
-    for i, v in zip(fixed_idx, fixed_vals):     # rare: seeded reserved rows
-        plane = plane.at[i].set(jnp.broadcast_to(v, shape))
+        tail = n_rows - N_RESERVED - len(vals)
+        if vals:
+            block = jnp.concatenate(
+                [jnp.broadcast_to(v, (1,) + shape) for v in vals])
+            plane = jnp.pad(block,
+                            ((N_RESERVED, tail),) + ((0, 0),) * len(shape))
+        else:
+            plane = jnp.zeros((n_rows,) + shape, jnp.uint32)
+        plane = plane.at[C1_IDX].set(jnp.full(shape, 0xFFFFFFFF, jnp.uint32))
+        for i, v in zip(fixed_idx, fixed_vals):  # rare: seeded reserved rows
+            plane = plane.at[i].set(jnp.broadcast_to(v, shape))
     if backend == "pallas":
         from repro.kernels.vm import vm_megakernel
 
@@ -518,7 +602,8 @@ def _dispatch(table, vals, fixed_vals=(), errors=None, mask=None, *, n_rows,
     return counts if reduce == "popcount" else weight_counts(counts)
 
 
-def execute_lowered(lp: LoweredProgram, data: Dict[str, jax.Array],
+def execute_lowered(lp: LoweredProgram,
+                    data: "Dict[str, jax.Array] | Gather",
                     row_words: Optional[int] = None,
                     outputs: Optional[List[str]] = None,
                     backend: str = "scan",
@@ -530,12 +615,15 @@ def execute_lowered(lp: LoweredProgram, data: Dict[str, jax.Array],
     Mirrors `engine.execute`: rows the program references but ``data`` does
     not provide are implicitly zero; rows in ``data`` the program never
     touches pass through unchanged; with ``outputs=None`` the returned dict
-    covers exactly the rows the interpreter would return. ``backend`` picks
-    the `jax.lax.scan` VM (``"scan"``) or the Pallas megakernel
-    (``"pallas"``, `kernels.vm`), which streams the plane through VMEM
-    block by block and loops the command table on-chip. Either way the
-    whole call — plane build, program execution, output extraction — is
-    one jitted dispatch.
+    covers exactly the rows the interpreter would return. ``data`` is a
+    dict of loose rows or a `Gather` (rows named by slot in one device
+    table, which the dispatch gathers from; the service scheduler passes
+    its catalog arena so); everything after the plane build is the same
+    code either way. ``backend`` picks the `jax.lax.scan` VM (``"scan"``)
+    or the Pallas megakernel (``"pallas"``, `kernels.vm`), which streams
+    the plane through VMEM block by block and loops the command table
+    on-chip. Either way the whole call — plane build, program execution,
+    output extraction — is one jitted dispatch.
 
     ``errors`` injects seeded TRA fault masks (`core.errors.error_planes`,
     shape ``(n_cmds, 4[, *batch], row_words)``) at compute time; masks are
@@ -560,14 +648,22 @@ def execute_lowered(lp: LoweredProgram, data: Dict[str, jax.Array],
         raise ValueError(f"unknown reduce mode {reduce!r}")
     if mask is not None and reduce is None:
         raise ValueError("mask= is only meaningful with a reduce mode")
-    # the plane's batch shape is the broadcast of every row's batch shape
-    # (right-aligned, like the interpreter's per-op jnp broadcasting):
-    # batched operands may be (..., X, W) while other rows are (W,)
-    shapes = [tuple(jnp.asarray(v).shape) for v in data.values()]
-    if row_words is None:
-        row_words = int(max(s[-1] for s in shapes))
-    batch = tuple(np.broadcast_shapes(*(s[:-1] for s in shapes)))
-    lay = _layout(lp, tuple(sorted(data)),
+    gather = data if isinstance(data, Gather) else None
+    names = gather.rows if gather is not None else data
+    if gather is not None:
+        batch = gather.batch
+        if row_words is None:
+            row_words = int(gather.source.shape[-1])
+    else:
+        # the plane's batch shape is the broadcast of every row's batch
+        # shape (right-aligned, like the interpreter's per-op jnp
+        # broadcasting): batched operands may be (..., X, W) while other
+        # rows are (W,)
+        shapes = [tuple(jnp.asarray(v).shape) for v in data.values()]
+        if row_words is None:
+            row_words = int(max(s[-1] for s in shapes))
+        batch = tuple(np.broadcast_shapes(*(s[:-1] for s in shapes)))
+    lay = _layout(lp, tuple(sorted(names)),
                   tuple(outputs) if outputs is not None else None)
     if errors is not None:
         errors = jnp.asarray(errors, jnp.uint32)
@@ -577,31 +673,42 @@ def execute_lowered(lp: LoweredProgram, data: Dict[str, jax.Array],
                 errors.reshape(errors.shape[:2]
                                + (1,) * (len(target) - errors.ndim)
                                + errors.shape[2:]), target)
-    seeded_fixed = tuple(n for n in FIXED_ROWS if n in data)
+    n_rows = lay.n_rows
+    if gather is not None:
+        idx = gather.index(lay)
+        n_rows = idx.shape[0]
+        planes = dict(vals=(), source=gather.source, idx=idx)
+        seeded_fixed = ()
+    else:
+        seeded_fixed = tuple(n for n in FIXED_ROWS if n in data)
+        planes = dict(
+            vals=tuple(jnp.asarray(data[k], jnp.uint32)
+                       for k in lay.val_names),
+            fixed_vals=tuple(jnp.asarray(data[n], jnp.uint32)
+                             for n in seeded_fixed))
     out_rows = _dispatch(
-        lay.table,
-        tuple(jnp.asarray(data[k], jnp.uint32) for k in lay.val_names),
-        tuple(jnp.asarray(data[n], jnp.uint32) for n in seeded_fixed),
-        errors,
-        None if mask is None else jnp.asarray(mask, jnp.uint32),
-        n_rows=lay.n_rows, out_runs=lay.out_runs,
+        lay.table, errors=errors,
+        mask=None if mask is None else jnp.asarray(mask, jnp.uint32),
+        n_rows=n_rows, out_runs=lay.out_runs,
         row_words=row_words, batch=batch, backend=backend,
         fixed_idx=tuple(FIXED_ROWS.index(n) for n in seeded_fixed),
-        reduce=reduce)
+        reduce=reduce, **planes)
     if reduce == "aggregate":
         return out_rows                 # (batch,) float32 weighted sum
     result = {o: out_rows[k] for k, o in enumerate(lay.out_names)}
-    passthrough = outputs if outputs is not None else data
+    passthrough = [n for n in (outputs if outputs is not None else names)
+                   if n not in result and n in names]
+    if gather is not None and passthrough:
+        data = gather.loose(passthrough)
     for name in passthrough:
-        if name not in result and name in data:
-            row = jnp.asarray(data[name], jnp.uint32)
-            if reduce == "popcount":
-                # count passthrough rows the same way the VM epilogue
-                # counts written rows (rare: a requested output the
-                # program never writes)
-                from repro.ops.popcount import popcount_words
+        row = jnp.asarray(data[name], jnp.uint32)
+        if reduce == "popcount":
+            # count passthrough rows the same way the VM epilogue counts
+            # written rows (rare: a requested output the program never
+            # writes)
+            from repro.ops.popcount import popcount_words
 
-                row = popcount_words(row if mask is None else row & mask,
-                                     axis=-1)
-            result[name] = row
+            row = popcount_words(row if mask is None else row & mask,
+                                 axis=-1)
+        result[name] = row
     return result

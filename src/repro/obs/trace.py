@@ -56,7 +56,7 @@ GROUP = "repro.group"
 # Leaves, in the order a tick runs them.
 TICK_FORM = "repro.tick.form"          # ServingLoop._form_tick
 TICK_PLAN = "repro.tick.plan"          # Scheduler.plan_queries
-GROUP_STACK = "repro.group.stack"      # the group's operand tensors
+GROUP_STACK = "repro.group.stack"      # the group's operand slot table
 GROUP_LAUNCH = "repro.group.launch"    # the executor call, to its return
 GROUP_SYNC = "repro.group.sync"        # host waits for counts / words
 GROUP_READOUT = "repro.group.readout"  # per-member scalars on the host

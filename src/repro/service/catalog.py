@@ -20,16 +20,29 @@ layout, so corresponding word-slots co-reside and queries over a group
 never move operand bits between chips. An elastic rescale re-attaches a
 new cluster and re-places every entry (slot contents are invariant; only
 the slot->chip assignment changes).
+
+Every vector of a catalog shares one width, so the words live as rows of
+one device-resident ``(capacity, 1, n_words)`` uint32 **arena** (the unit
+axis keeps a row contiguous in a TPU's tiled layout): an entry is its
+row (`CatalogEntry.slot`), and the arena is the only device copy of the
+words. Rows `ZERO_SLOT` and `ONES_SLOT` hold all-zero and all-one
+words, so a plan group's whole plane tensor — reserved rows, operands,
+zero tail — is one gather by slot (`lowering.Gather`) inside the group's
+compiled dispatch. Registration writes one row in place (a donated
+update) and the arena doubles when full.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import re
 from typing import Dict, Iterable, List, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from repro.core import lowering
 from repro.core.allocator import DramAllocator, RowHandle
 from repro.core.bitplane import BitVector, n_words, pack_bits, tail_mask
 
@@ -38,6 +51,23 @@ from repro.core.bitplane import BitVector, n_words, pack_bits, tail_mask
 _RESERVED_RE = re.compile(
     r"^(B\d+|C[01]|T[0-3]|DCC[01]|TMP\d*|IN\d+|OUT)$")
 _NAME_RE = re.compile(r"^[A-Za-z_][\w./:-]*$")
+
+#: arena rows every catalog holds: all-zero and all-one words
+ZERO_SLOT, ONES_SLOT = 0, 1
+_FIRST_CAPACITY = 32
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _write_row(arena: jax.Array, slot: jax.Array,
+               words: jax.Array) -> jax.Array:
+    """``arena`` with row ``slot`` set to ``words``, updated in place."""
+    return jax.lax.dynamic_update_slice_in_dim(arena, words[None, None],
+                                               slot, 0)
+
+
+@jax.jit
+def _read_row(arena: jax.Array, slot: jax.Array) -> jax.Array:
+    return jax.lax.dynamic_index_in_dim(arena, slot, 0, keepdims=False)[0]
 
 
 class CatalogError(KeyError):
@@ -80,10 +110,10 @@ class ChipPlacement:
 
 @dataclasses.dataclass
 class CatalogEntry:
-    """One registered bitvector: packed words + modeled DRAM placement."""
+    """One registered bitvector: its arena row + modeled DRAM placement."""
 
     name: str
-    words: jax.Array          # (n_words,) uint32, LSB-first packed
+    slot: int                 # row of the catalog's arena
     n_bits: int
     handle: RowHandle         # (bank, subarray, row) placement
     group: Optional[str] = None
@@ -91,6 +121,19 @@ class CatalogEntry:
     #: and its layout record (None until a cluster is attached)
     shards: Optional[jax.Array] = None
     placement: Optional[ChipPlacement] = None
+    catalog: Optional["Catalog"] = dataclasses.field(
+        default=None, repr=False, compare=False)
+
+    @property
+    def words(self) -> jax.Array:
+        """The (n_words,) uint32 LSB-first packed words: the arena row."""
+        return _read_row(self.catalog.arena, np.int32(self.slot))
+
+    @words.setter
+    def words(self, value) -> None:
+        # overwrites the stored row (a corruption at rest, to the parity
+        # probe); registration is the way to add data
+        self.catalog._write(self.slot, value)
 
     @property
     def n_row_blocks(self) -> int:
@@ -120,6 +163,12 @@ class Catalog:
         # (None = single-process catalog, the pre-cluster behavior)
         self._cluster = None
         self._mask_shards: Optional[jax.Array] = None
+        # the words of every entry, one row each (None until the first
+        # registration pins the width); rows past _n_slots, and released
+        # scratch rows in _free, are unused
+        self._arena: Optional[jax.Array] = None
+        self._n_slots = 2
+        self._free: List[int] = []
         # ECC: running XOR parity plane per affinity group (None key =
         # ungrouped), maintained incrementally at registration time —
         # `verify_parity` recomputes from scratch and cross-checks, the
@@ -155,7 +204,10 @@ class Catalog:
             raise CatalogError(
                 f"{name!r}: domain {n_bits} != catalog domain {self.n_bits}")
         handle = self.allocator.alloc(name, n_bits, group=group)
-        entry = CatalogEntry(name, words, n_bits, handle, group=group)
+        slot = self._take_slot()
+        self._write(slot, words)
+        entry = CatalogEntry(name, slot, n_bits, handle, group=group,
+                             catalog=self)
         self._entries[name] = entry
         prev = self._parity.get(group)
         cur = jnp.asarray(words, jnp.uint32)
@@ -185,6 +237,48 @@ class Catalog:
             self.register(plane_name(name, j), planes[j], n_values,
                           group=group)
         self.columns[name] = n_bits
+
+    # -- the arena -----------------------------------------------------------
+
+    @property
+    def arena(self) -> jax.Array:
+        """The ``(capacity, 1, n_words)`` uint32 device rows of every
+        entry; ``arena[slot, 0]`` is one entry's words."""
+        assert self._arena is not None, "empty catalog has no arena"
+        return self._arena
+
+    def _take_slot(self) -> int:
+        if self._free:
+            return self._free.pop()
+        if self._arena is None:
+            words = n_words(self.n_bits)
+            self._arena = jnp.zeros((_FIRST_CAPACITY, 1, words), jnp.uint32)
+            self._write(ONES_SLOT, np.full(words, 0xFFFFFFFF, np.uint32))
+        elif self._n_slots == self._arena.shape[0]:
+            # doubling: a copy per doubling, none per registration
+            self._arena = jnp.pad(self._arena,
+                                  ((0, self._arena.shape[0]), (0, 0), (0, 0)))
+        self._n_slots += 1
+        return self._n_slots - 1
+
+    def _write(self, slot: int, words) -> None:
+        self._arena = _write_row(self._arena, np.int32(slot),
+                                 jnp.asarray(words, jnp.uint32))
+
+    def scratch_slot(self, words) -> int:
+        """Write a plane that lives for one batch (a shared-subexpression
+        plane) into a free row; `release` gives the row back."""
+        slot = self._take_slot()
+        self._write(slot, words)
+        return slot
+
+    def release(self, slots: Iterable[int]) -> None:
+        self._free.extend(slots)
+
+    def gather(self, rows: Dict[str, np.ndarray]) -> lowering.Gather:
+        """Operand rows ``{name: slots}`` of a dispatch, named by arena row
+        (`lowering.execute_lowered` builds the plane as one gather)."""
+        return lowering.Gather(self.arena, rows, ZERO_SLOT, ONES_SLOT)
 
     # -- lookup -------------------------------------------------------------
 

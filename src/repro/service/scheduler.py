@@ -287,28 +287,49 @@ class Scheduler:
             self.telemetry.metrics.counter(
                 "plan_group_dispatches_total", backend=backend).inc()
 
-    def _operand_words(self, name: str,
-                       cse_planes: Optional[Dict[str, jax.Array]]):
-        """A bound operand's packed words: catalog row or shared plane."""
-        if cse_planes is not None and name.startswith(CSE_PREFIX):
-            return cse_planes[name]
-        return self.catalog.get(name).words
+    def _count_operand_rows(self, path: str, n: int) -> None:
+        """Count the operand rows a group placed, by how they reached the
+        plane (``plan_group_operand_rows_total{path="gather"|"stack"}``)."""
+        if self.telemetry.metering:
+            self.telemetry.metrics.counter(
+                "plan_group_operand_rows_total", path=path).inc(n)
+
+    def _operand_slots(self, members: List[Tuple[int, BoundPlan]],
+                       cse_slots: Optional[Dict[str, int]]
+                       ) -> lowering.Gather:
+        """The group's operands by arena row: canonical input IN{i} ->
+        one slot per member, a catalog entry's or a shared plane's."""
+        cat = self.catalog
+        cse_slots = cse_slots or {}
+        input_rows = [bp.input_map() for _, bp in members]
+        rows = {
+            name: np.fromiter(
+                (cse_slots[r[name]] if r[name] in cse_slots
+                 else cat.get(r[name]).slot for r in input_rows),
+                np.int32, len(input_rows))
+            for name in input_rows[0]
+        }
+        self._count_operand_rows("gather", len(rows) * len(members))
+        return cat.gather(rows)
 
     # -- functional execution ------------------------------------------------
 
     def _run_group(self, members: List[Tuple[int, BoundPlan]],
                    need_words: bool,
-                   cse_planes: Optional[Dict[str, jax.Array]] = None
+                   cse_slots: Optional[Dict[str, int]] = None
                    ) -> Tuple[Optional[np.ndarray], List[int], int]:
         """One stacked VM dispatch for all queries sharing a plan.
 
-        Stacks each canonical input IN{i} across the group's queries into a
-        leading query axis — exactly the bank-axis layout of
-        `core.bankgroup.BankGroup` (one broadcast program, per-bank data) —
-        and executes the plan's cached `LoweredProgram` through the scan VM
-        or Pallas megakernel: the whole group is ONE kernel launch over a
-        ``(n_rows, n_queries, n_words)`` plane tensor, no per-query
-        tracing. Returns (masked result words (len(members), n_outputs,
+        Names each canonical input IN{i} of each of the group's queries by
+        its catalog arena row (or a shared plane's scratch row, from
+        ``cse_slots``) — a host-built slot table with a query axis, exactly
+        the bank-axis layout of `core.bankgroup.BankGroup` (one broadcast
+        program, per-bank data) — and executes the plan's cached
+        `LoweredProgram` through the scan VM or Pallas megakernel: the
+        whole group is ONE compiled dispatch that gathers its
+        ``(n_rows, n_queries, n_words)`` plane tensor from the arena and
+        runs it, no per-query tracing and no host copy of an operand.
+        Returns (masked result words (len(members), n_outputs,
         n_words) or None when no member materializes, per-query scalars,
         replicas run) — the scalar is sum_j 2**j * popcount(output plane
         j), which for single-output boolean plans is exactly the popcount.
@@ -323,12 +344,7 @@ class Scheduler:
         tr = self.telemetry.tracer
         phases = self._phase_us
         with tr.phase(GROUP_STACK, phases):
-            input_rows = [bp.input_map() for _, bp in members]
-            data = {
-                name: jnp.stack([self._operand_words(rows[name], cse_planes)
-                                 for rows in input_rows])
-                for name in input_rows[0]
-            }
+            data = self._operand_slots(members, cse_slots)
         plan = members[0][1].plan
         # per-plan backend choice recorded by the optimizer wins over the
         # scheduler default (mitigated dispatch stays on the VM, where
@@ -363,11 +379,11 @@ class Scheduler:
             if (rel is not None and rel.mode != "none"
                     and plan.lowered is not None):
                 backend = self.backend  # mitigation runs on the default VM
-                out, replicas = self._run_reliable(plan, data)
+                out, replicas = self._run_reliable(plan, data.loose())
             elif backend == "interp":
                 # degenerate 1-2 command programs: eager micro-op
                 # interpreter, a VM launch would cost more than the program
-                out = engine.execute(plan.program, data,
+                out = engine.execute(plan.program, data.loose(),
                                      outputs=list(plan.outputs),
                                      lowered=False)
             elif plan.lowered is not None:
@@ -376,7 +392,7 @@ class Scheduler:
                     backend=backend)
             else:   # plans built outside the cache fall back to the engine
                 backend = self.backend
-                out = engine.execute(plan.program, data,
+                out = engine.execute(plan.program, data.loose(),
                                      outputs=list(plan.outputs),
                                      backend=self.backend)
             self._count_dispatch(backend)
@@ -434,7 +450,7 @@ class Scheduler:
 
     def _run_group_resilient(self, members: List[Tuple[int, BoundPlan]],
                              need_words: bool,
-                             cse_planes: Optional[Dict[str, jax.Array]] = None
+                             cse_slots: Optional[Dict[str, int]] = None
                              ) -> Tuple[Optional[np.ndarray], List[int], int]:
         """`_run_group` under the fault policy: timed, replayed, flagged.
 
@@ -457,7 +473,7 @@ class Scheduler:
             try:
                 if ft.failure_injector is not None:
                     ft.failure_injector(g)
-                out = self._run_group(members, need_words, cse_planes)
+                out = self._run_group(members, need_words, cse_slots)
             except Exception as e:  # noqa: BLE001 - any failure is replayable
                 ft.failures += 1
                 ft.timeline.append(f"failure@group{g}:{type(e).__name__}")
@@ -512,6 +528,7 @@ class Scheduler:
                                  for rows in input_rows], axis=2)
                 for name in input_rows[0]
             }
+            self._count_operand_rows("stack", len(data) * len(members))
         plan = members[0][1].plan
         # shard_map dispatch needs a lowered VM: honor the optimizer's
         # backend only when it is one ("interp" falls back to the default)
@@ -539,9 +556,8 @@ class Scheduler:
             out = cluster.run_lowered(lp, data, plan.outputs,
                                       backend=backend)
         with tr.phase(GROUP_SYNC, phases):
-            n_words = self.catalog.get(
-                next(iter(input_rows[0].values()))).words.shape[0]
             mask = self.catalog.mask()
+            n_words = mask.shape[0]
             # (n_outputs, len(members), n_words) -> query-major, as in the
             # single-process path
             masked = jnp.stack(
@@ -644,38 +660,44 @@ class Scheduler:
             bound, cse = orig_bound, None
 
         # 1b. shared-subexpression planes execute first (topo order), ONE
-        #     dispatch each; consumers read them as input leaves below
-        cse_planes: Dict[str, jax.Array] = {}
-        if cse is not None:
-            for d in cse.defs:
-                if tracing:
-                    tr.begin("cse_group", plane=d.name, uses=d.uses,
-                             n_aaps=d.bound.plan.n_aaps)
-                    tr.begin("cse_dispatch")
-                stacked, _, _ = self._run_group([(0, d.bound)], True,
-                                                cse_planes)
-                cse_planes[d.name] = jnp.asarray(stacked[0][0])
-                if tracing:
-                    tr.end()    # cse_dispatch
-                    tr.end()    # cse_group
-            self.cse_planes_built += len(cse.defs)
-            if tel.metering:
-                self._m_cse.inc(len(cse.defs))
+        #     dispatch each, into scratch rows of the catalog arena for the
+        #     batch; consumers gather them as input leaves below
+        cse_slots: Dict[str, int] = {}
+        try:
+            if cse is not None:
+                for d in cse.defs:
+                    if tracing:
+                        tr.begin("cse_group", plane=d.name, uses=d.uses,
+                                 n_aaps=d.bound.plan.n_aaps)
+                        tr.begin("cse_dispatch")
+                    stacked, _, _ = self._run_group([(0, d.bound)], True,
+                                                    cse_slots)
+                    cse_slots[d.name] = self.catalog.scratch_slot(
+                        stacked[0][0])
+                    if tracing:
+                        tr.end()    # cse_dispatch
+                        tr.end()    # cse_group
+                self.cse_planes_built += len(cse.defs)
+                if tel.metering:
+                    self._m_cse.inc(len(cse.defs))
 
-        # 2. group by canonical plan -> one stacked dispatch per group
-        groups: Dict[Tuple, List[Tuple[int, BoundPlan]]] = {}
-        for idx, bp in enumerate(bound):
-            groups.setdefault(bp.plan.key, []).append((idx, bp))
-        dispatch = (self._run_group_resilient
-                    if self.fault_tolerance is not None else self._run_group)
-        ran = []
-        for members in groups.values():
-            need_words = any(queries[idx].mode == MATERIALIZE
-                             for idx, _ in members)
-            with tr.phase(GROUP, None, n_queries=len(members),
-                          n_aaps=members[0][1].plan.n_aaps):
-                ran.append((members,
-                            *dispatch(members, need_words, cse_planes)))
+            # 2. group by canonical plan -> one stacked dispatch per group
+            groups: Dict[Tuple, List[Tuple[int, BoundPlan]]] = {}
+            for idx, bp in enumerate(bound):
+                groups.setdefault(bp.plan.key, []).append((idx, bp))
+            dispatch = (self._run_group_resilient
+                        if self.fault_tolerance is not None
+                        else self._run_group)
+            ran = []
+            for members in groups.values():
+                need_words = any(queries[idx].mode == MATERIALIZE
+                                 for idx, _ in members)
+                with tr.phase(GROUP, None, n_queries=len(members),
+                              n_aaps=members[0][1].plan.n_aaps):
+                    ran.append((members,
+                                *dispatch(members, need_words, cse_slots)))
+        finally:
+            self.catalog.release(cse_slots.values())
 
         with tr.phase(TICK_ACCOUNT, phases):
             report = self._account(queries, orig_bound, bound, cse, ran, tel)

@@ -155,13 +155,18 @@ class QueryService:
 
     # -- catalog management --------------------------------------------------
 
+    # registration writes the catalog arena in place, so it holds the
+    # dispatch lock: a live loop's group never reads a replaced arena
+
     def register(self, name: str, value, n_bits: Optional[int] = None,
                  group: Optional[str] = None) -> CatalogEntry:
-        return self.catalog.register(name, value, n_bits, group)
+        with self._dispatch_lock:
+            return self.catalog.register(name, value, n_bits, group)
 
     def register_bits(self, name: str, bits,
                       group: Optional[str] = None) -> CatalogEntry:
-        return self.catalog.register_bits(name, bits, group)
+        with self._dispatch_lock:
+            return self.catalog.register_bits(name, bits, group)
 
     def register_column(self, name: str, values: jax.Array, n_bits: int,
                         group: Optional[str] = None) -> VerticalColumn:
@@ -179,8 +184,9 @@ class QueryService:
             raise ValueError(
                 f"column {name!r}: {col.n_values} values != catalog domain "
                 f"{self.catalog.n_bits}")
-        self.catalog.register_column(name, col.planes, col.n_values, n_bits,
-                                     group=group)
+        with self._dispatch_lock:
+            self.catalog.register_column(name, col.planes, col.n_values,
+                                         n_bits, group=group)
         self._columns[name] = col
         return col
 
@@ -197,8 +203,9 @@ class QueryService:
         assert self.catalog.n_bits is not None
         col = VerticalColumn(planes, int(planes.shape[0]),
                              self.catalog.n_bits)
-        self.catalog.register_column(name, planes, self.catalog.n_bits,
-                                     col.n_bits, group=group)
+        with self._dispatch_lock:
+            self.catalog.register_column(name, planes, self.catalog.n_bits,
+                                         col.n_bits, group=group)
         self._columns[name] = col
         return col
 
@@ -283,8 +290,7 @@ class QueryService:
                     group: Optional[str] = None) -> CatalogEntry:
         """Run `query`, register its result vector under `name`."""
         r = self.query(query, mode=MATERIALIZE)
-        return self.catalog.register(name, r.value, self.catalog.n_bits,
-                                     group=group)
+        return self.register(name, r.value, self.catalog.n_bits, group=group)
 
     # -- range scans ---------------------------------------------------------
 

@@ -19,7 +19,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import lowering
 from repro.kernels import (arith, bittranspose, bitweaving, bitwise,
-                           majority, popcount, vm)
+                           gather, majority, popcount, vm)
 
 WORDS = 524_288            # 1 << 24 bits
 N_ROWS = 40
@@ -46,8 +46,8 @@ def one_chip(topo):
 @pytest.fixture
 def compiled(monkeypatch):
     """Steer the kernels off interpret mode: compile them for the chip."""
-    for mod in (vm, arith, bittranspose, bitweaving, bitwise, majority,
-                popcount):
+    for mod in (vm, arith, bittranspose, bitweaving, bitwise, gather,
+                majority, popcount):
         monkeypatch.setattr(mod, "use_interpret", lambda: False)
 
 
@@ -95,9 +95,27 @@ def test_dispatch_compiles(backend, reduce, one_chip, compiled):
     assert _has_kernel(lowered) == (backend == "pallas")
 
 
+@pytest.mark.parametrize("backend", ["scan", "pallas"])
+@pytest.mark.parametrize("reduce", [None, "popcount"])
+def test_gathered_dispatch_compiles(backend, reduce, one_chip, compiled):
+    """The served form of `lowering._dispatch`: the plane gathered from a
+    catalog arena by a slot table (`kernels.gather`), then the VM."""
+    n_rows = 40                    # a whole number of 8-row tiles
+    mask = _sds((WORDS,), one_chip) if reduce else None
+    lowered = lowering._dispatch.lower(
+        _sds((N_CMDS, 5), one_chip, jnp.int32), errors=None, mask=mask,
+        vals=(), source=_sds((64, 1, WORDS), one_chip),
+        idx=_sds((n_rows, BATCH), one_chip, jnp.int32),
+        n_rows=n_rows, out_runs=((OUT_IDX[0], OUT_IDX[-1] + 1),),
+        row_words=WORDS, batch=(BATCH,), backend=backend, reduce=reduce)
+    text = lowered.compile().as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == (
+        2 if backend == "pallas" else 1)
+
+
 @pytest.mark.parametrize("kernel", ["bitwise", "bitwise_banked", "popcount",
                                     "bitweaving", "add", "lt", "majority",
-                                    "transpose", "untranspose"])
+                                    "transpose", "untranspose", "gather"])
 def test_per_op_kernel_compiles(kernel, one_chip, compiled):
     """The per-operation kernels behind `repro.ops`, one call each; the
     transpose encodes every registered column (`register_column`)."""
@@ -120,6 +138,9 @@ def test_per_op_kernel_compiles(kernel, one_chip, compiled):
             s(3, 8, WORDS), None),
         "transpose": lambda: bittranspose.bit_transpose_kernel.lower(
             s(32 * WORDS)),
+        "gather": lambda: gather._gather_call.lower(
+            s(64, 1, WORDS), _sds((80,), one_chip, jnp.int32),
+            block_cols=gather.DEFAULT_BLOCK_COLS),
         "untranspose": lambda: bittranspose.bit_untranspose_kernel.lower(
             s(32, WORDS)),
     }[kernel]()
