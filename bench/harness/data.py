@@ -14,9 +14,13 @@ A configuration file (``bench/configs/<name>.json``) lists
                   an integer column, each value the sum of independent
                   uniform draws from the listed inclusive ranges
 
-All of it is made by one jitted call from the seed. Bitmaps are packed
-little-endian into uint32 words, bit i of the vector in bit i % 32 of
-word i // 32, which is the layout the catalog takes.
+Every item (one bitmap, or one column) has a key of its own, split from
+the seed in registration order, and is made from it by one jitted call
+that compiles once for each kind and shape of item. `build` makes,
+copies and registers one item at a time, so a catalog is not limited by
+what one device holds beside it. Bitmaps are packed little-endian into
+uint32 words, bit i of the vector in bit i % 32 of word i // 32, which is
+the layout the catalog takes.
 """
 from __future__ import annotations
 
@@ -82,28 +86,23 @@ def _column(key, n: int, ranges, bits: int):
     return total.astype(_column_dtype(bits))
 
 
-@functools.partial(jax.jit, static_argnames=("spec", "n"))
-def _generate(seed_words, *, spec: Tuple[Item, ...], n: int):
-    """Every item of ``spec`` from one key: bitmaps with the same p are
-    made by one sequential map, so no more than one vector's random bits
-    are live at a time."""
+@functools.partial(jax.jit, static_argnames=("n",))
+def _keys(seed_words, *, n: int):
+    """The ``n`` item keys of a seed, item i's at row i."""
     key = jax.random.fold_in(jax.random.fold_in(jax.random.PRNGKey(0),
                                                 seed_words[0]),
                              seed_words[1])
-    keys = jax.random.split(key, len(spec))
-    out: List[jax.Array] = [None] * len(spec)
-    by_p: Dict[float, List[int]] = {}
-    for i, it in enumerate(spec):
-        if it.kind == "bitmap":
-            by_p.setdefault(it.p, []).append(i)
-        else:
-            out[i] = _column(keys[i], n, it.ranges, it.bits)
-    for p, idx in by_p.items():
-        stack = jax.lax.map(lambda k: _bitmap(k, n // 32, p),
-                            keys[jnp.asarray(idx)])
-        for j, i in enumerate(idx):
-            out[i] = stack[j]
-    return tuple(out)
+    return jax.random.split(key, n)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("kind", "n", "p", "bits", "ranges"))
+def _item(key, *, kind: str, n: int, p: float, bits: int, ranges):
+    """One item from its key: one executable a kind and shape, whatever
+    the item's name."""
+    if kind == "bitmap":
+        return _bitmap(key, n // 32, p)
+    return _column(key, n, ranges, bits)
 
 
 def seed_words(seed: int) -> np.ndarray:
@@ -124,32 +123,46 @@ class HostCopy:
     n_rows: int
 
 
-def generate(cfg: dict, seed: int):
-    """(items, device arrays) of a configuration for ``seed``."""
+def _made(cfg: dict, seed: int):
+    """Each item of a configuration with its device array, in registration
+    order, made only when the caller asks for it."""
     n = int(cfg["domain_bits"])
     if n % 32:
         raise ValueError(f"domain_bits {n} is not a multiple of 32")
-    spec = tuple(items(cfg))
-    arrays = _generate(jnp.asarray(seed_words(seed)), spec=spec, n=n)
-    return spec, arrays
+    spec = items(cfg)
+    keys = np.asarray(_keys(jnp.asarray(seed_words(seed)), n=len(spec)))
+    for it, key in zip(spec, keys):
+        yield it, _item(key, kind=it.kind, n=n, p=it.p, bits=it.bits,
+                        ranges=it.ranges)
+
+
+def generate(cfg: dict, seed: int):
+    """(items, device arrays) of a configuration for ``seed``, all of it
+    on the device at once: for small configurations (`build` holds one
+    item at a time)."""
+    made = list(_made(cfg, seed))
+    return tuple(it for it, _ in made), tuple(a for _, a in made)
 
 
 def build(svc, cfg: dict, seed: int) -> HostCopy:
     """Register a configuration's data in ``svc``'s catalog; return the
     host copy. Bitmaps go in as packed device words (`register`), columns
-    as device values that the program transposes (`register_column`)."""
-    n = int(cfg["domain_bits"])
-    spec, arrays = generate(cfg, seed)
-    host = jax.device_get(arrays)
-    copy = HostCopy({}, {}, {}, n)
-    for it, dev, h in zip(spec, arrays, host):
+    as device values that the program transposes (`register_column`).
+    One item at a time: it is made, copied to the host, registered, and
+    dropped before the next is made, so the device holds no more of the
+    data than the catalog does, plus one item. The host copy is a copy
+    (`np.array`): a view, as `np.asarray` gives on the CPU, would keep
+    each item's device array alive."""
+    copy = HostCopy({}, {}, {}, int(cfg["domain_bits"]))
+    for it, dev in _made(cfg, seed):
         if it.kind == "bitmap":
-            svc.register(it.name, dev, n, group=it.group)
-            copy.bitmaps[it.name] = np.asarray(h)
+            copy.bitmaps[it.name] = np.array(dev)
+            svc.register(it.name, dev, copy.n_rows, group=it.group)
         else:
-            svc.register_column(it.name, dev, it.bits, group=it.group)
-            copy.columns[it.name] = np.asarray(h)
+            copy.columns[it.name] = np.array(dev)
             copy.bits[it.name] = it.bits
+            svc.register_column(it.name, dev, it.bits, group=it.group)
+        del dev
     return copy
 
 

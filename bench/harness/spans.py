@@ -12,9 +12,18 @@ spans that covers it (`harness.xtrace`).
     bench.form      ServingLoop._form_tick: pick the tick's queries
     bench.plan      Scheduler.plan_queries: parse, plan, bind
     bench.tick      Scheduler.submit: one tick's groups and bookkeeping
-    bench.group     Scheduler._run_group: stack, launch, read out a group
+    bench.group     Scheduler._run_group: one plan group, from its operand
+                    table (one chip: the numpy table of the group's catalog
+                    arena rows, gathered inside the dispatch; several
+                    chips: one `jnp.stack` of the sharded copies an
+                    operand) through launch, wait and readout
     bench.launch    lowering.execute_lowered: enqueue the group's program
     bench.finalize  ServingLoop._finalize: resolve the tick's handles
+
+`bench.launch` wraps `lowering.execute_lowered` only, which the one-chip
+path calls. The sharded path (`Scheduler._run_group_sharded`) launches
+through the chip cluster instead, so there its launch lies under
+`bench.group`, with no `bench.launch` inside.
 """
 from __future__ import annotations
 

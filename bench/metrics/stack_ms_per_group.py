@@ -1,8 +1,13 @@
 """stack_ms_per_group: host time in the program's `repro.group.stack`
-span (building a plan group's operand tensors, one stack an operand),
-summed per tick in `TickStats.phase_us`, over the window's plan groups
-(`TickStats.n_groups`), in ms a group. None where the program keeps no
-phase totals."""
+span, summed per tick in `TickStats.phase_us`, over the window's plan
+groups (`TickStats.n_groups`), in ms a group. None where the program keeps
+no phase totals.
+
+The span times how a group's operands are named for its dispatch. On one
+chip that is the numpy slot table of the group's catalog arena rows; the
+rows themselves are gathered on the device inside the dispatch, so no
+device operation runs in the span. On several chips it is one
+`jnp.stack` of the chip-sharded copies an operand."""
 
 
 def read(run):
